@@ -26,6 +26,8 @@ import os
 import sys
 import traceback
 
+from repro.launch.runtime import enable_compile_cache, fake_host_devices
+
 # modules whose rows land in BENCH_routing.json (the event-delivery hot path)
 _ROUTING_MODULES = ("routing_throughput", "dispatch", "serving")
 
@@ -47,7 +49,8 @@ def main(argv: list[str] | None = None) -> None:
         metavar="N",
         help="fake N host-platform devices (sets "
         "--xla_force_host_platform_device_count before jax imports; the "
-        "sharded serving rows then run shards on disjoint devices)",
+        "sharded serving rows then run shards on disjoint devices). "
+        "CPU only: refused when the backend is an accelerator",
     )
     ap.add_argument(
         "--only",
@@ -59,14 +62,8 @@ def main(argv: list[str] | None = None) -> None:
     )
     args = ap.parse_args(argv)
     if args.devices is not None:
-        if "jax" in sys.modules:
-            raise SystemExit(
-                "--devices must take effect before jax is imported"
-            )
-        flags = os.environ.get("XLA_FLAGS", "")
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={args.devices}"
-        ).strip()
+        fake_host_devices(args.devices)
+    enable_compile_cache()
     only = args.only.split(",") if args.only else None
     if args.profile is not None:
         import jax

@@ -67,6 +67,9 @@ def main():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src")
+    # fake CPU devices; the child never reaches for an accelerator, which
+    # the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(BODY)],
                          env=env, cwd=root, text=True)

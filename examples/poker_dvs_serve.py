@@ -18,39 +18,15 @@ Run: PYTHONPATH=src python examples/poker_dvs_serve.py
 """
 
 import argparse
-import dataclasses
 import time
 
-import jax.numpy as jnp
 import numpy as np
 
-from repro.core.cnn import (
-    CnnConfig,
-    compile_poker_cnn,
-    hebbian_readout_select,
-    poker_neuron_params,
-)
-from repro.core.compiler import Geometry, artifact_from_tables
-from repro.core.event_engine import EventEngine
-from repro.data.pipeline import DvsStreamConfig, DvsStreamSource, symbol_dvs_events
-from repro.serve.aer import AerServeConfig, AerSessionPool, DvsSession, build_poker_engine
+from repro.data.pipeline import DvsStreamConfig, DvsStreamSource
+from repro.launch.runtime import enable_compile_cache
+from repro.serve.aer import AerServeConfig, AerSessionPool, DvsSession, table_v_models
 
 SUITS = ["diamond(|)", "club(-)", "spade(^)", "heart(v)"]
-
-
-def tune_readout(rng) -> np.ndarray:
-    """Offline-Hebbian readout selection (one batched calibration run)."""
-    cc = compile_poker_cnn()
-    eng = EventEngine(cc.tables, poker_neuron_params())
-    t_steps, reps = 40, 3
-    streams = [symbol_dvs_events(sym, 400, rng) for sym in range(4) for _ in range(reps)]
-    act = cc.input_activity_batch(streams) / t_steps * 10.0
-    inp = jnp.broadcast_to(jnp.asarray(act)[None], (t_steps, *act.shape))
-    _, spikes = eng.run(eng.init_state(batch=len(streams)), inp)
-    pool_rates = (
-        np.asarray(spikes)[:, :, cc.pool[0]: cc.pool[1]].sum(0).reshape(4, reps, -1).sum(1)
-    )
-    return hebbian_readout_select(pool_rates)
 
 
 def main():
@@ -62,25 +38,12 @@ def main():
     ap.add_argument("--events-per-step", type=int, default=16)
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(args.seed)
-    fc_select = tune_readout(rng)
-    cc = compile_poker_cnn(CnnConfig(), fc_select=fc_select)
-
-    # second resident model (DESIGN.md §16): the SAME Table-V network bound
-    # to a 2x2-chip geometry (2 cores/chip — the smallest mesh its 6 cores
-    # fit). Placement-only retarget: the CNN's spliced input taps live in
-    # the CAM words, so the tables are re-placed, never recompiled.
-    geo2 = Geometry(grid_x=2, grid_y=2, cores_per_tile=2, neurons_per_core=256)
-    art2 = artifact_from_tables(cc.tables, geo2, optimize=False)
-    # the 2x2 placement binds to the 2x2 mesh, not the pool's shared serving
-    # fabric — placements compose all-or-none across residents (DESIGN.md
-    # §18), so the resident copy is stripped back to the fabric default and
-    # art2 keeps the feasibility story
-    cc2 = dataclasses.replace(
-        cc, tables=dataclasses.replace(art2.tables, tile_of_cluster=None)
-    )
-    models = {"tableV-3x3": cc, "tableV-2x2": cc2}
+    # the Table-V network resident twice: 3x3-chip and 2x2-chip placements
+    models = table_v_models(rng)
+    cc = models["tableV-3x3"]
     pool = AerSessionPool.from_models(
         models, AerServeConfig(pool_size=args.pool), backend=args.backend
     )
@@ -88,9 +51,7 @@ def main():
           f"{cc.tables.n_clusters} cores) resident twice — 3x3-chip and "
           f"2x2-chip placements ({pool.engine.n_neurons} neurons combined) — "
           f"served via backend={args.backend!r}, pool of {args.pool} slots, "
-          f"{args.sessions} sessions "
-          f"(2x2 binding budget: {art2.feasibility.binding} at "
-          f"{art2.feasibility.utilization[art2.feasibility.binding]:.0%})")
+          f"{args.sessions} sessions")
 
     names = list(models)
     suits = rng.integers(0, 4, args.sessions)

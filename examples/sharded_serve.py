@@ -17,12 +17,11 @@ Run: PYTHONPATH=src python examples/sharded_serve.py
      PYTHONPATH=src python examples/sharded_serve.py --devices 4 --backend fabric
 
 ``--devices N`` fakes N host devices (must be set before jax initializes),
-giving each shard a disjoint device set as on a real multi-host fleet.
+giving each shard a disjoint device set as on a real multi-host fleet. It
+is refused on an accelerator, where the shards take the real devices.
 """
 
 import argparse
-import os
-import sys
 import tempfile
 import time
 
@@ -35,17 +34,16 @@ def main() -> None:
     ap.add_argument("--backend", default="fabric",
                     choices=["reference", "fused", "fabric"])
     ap.add_argument("--devices", type=int, default=None,
-                    help="fake N host devices (shards get disjoint sets)")
+                    help="fake N host devices (shards get disjoint sets; "
+                    "CPU only)")
     ap.add_argument("--seed", type=int, default=11)
     args = ap.parse_args()
 
+    from repro.launch.runtime import enable_compile_cache, fake_host_devices
+
     if args.devices is not None:
-        if "jax" in sys.modules:
-            raise SystemExit("--devices must be set before jax is imported")
-        flags = os.environ.get("XLA_FLAGS", "")
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={args.devices}"
-        ).strip()
+        fake_host_devices(args.devices)
+    enable_compile_cache()
 
     import numpy as np
 

@@ -1246,7 +1246,11 @@ def dense_reference_step(
     i_ext: jax.Array | None = None,
 ):
     """Oracle step: dense matmul delivery instead of two-stage routing."""
-    drive = jnp.einsum("dst,...s->...dt", dense_w, prev_spikes)
+    # f32 throughout: HIGHEST keeps a TPU from rounding the operands to bf16
+    drive = jnp.einsum(
+        "dst,...s->...dt", dense_w, prev_spikes,
+        precision=jax.lax.Precision.HIGHEST,
+    )
     if external_drive is not None:
         drive = drive + external_drive
     return neuron_mod.neuron_step(state, drive, params, i_ext)

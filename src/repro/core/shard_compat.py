@@ -1,45 +1,21 @@
-"""Version-portable ``shard_map`` plumbing shared by core and models.
+"""``shard_map`` plumbing shared by core and models.
 
-JAX has moved ``shard_map`` (experimental -> top-level) and renamed its
-replication-check kwarg (``check_rep`` -> ``check_vma``) across releases.
-Every ``shard_map`` call site in this repo resolves the function and the
-kwarg through this module so the dance lives in exactly one place
-(previously it was duplicated in models/moe.py and core/event_engine.py,
-with core importing from models — a layering inversion).
+Every ``shard_map`` call site in this repo takes the function and its
+replication-check switch from here, so the choice lives in one place
+(core never imports from models for it).
 """
 
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax import shard_map
 
-try:  # jax >= 0.6
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
-
-try:
-    _params = inspect.signature(shard_map).parameters
-    if "check_vma" in _params:
-        SM_CHECK_KW = {"check_vma": False}
-    elif "check_rep" in _params:
-        SM_CHECK_KW = {"check_rep": False}
-    else:  # pragma: no cover
-        SM_CHECK_KW = {}
-except Exception:  # pragma: no cover
-    SM_CHECK_KW = {}
+SM_CHECK_KW = {"check_vma": False}
 
 
 def axis_size(axis) -> int:
-    """Static size of a named mesh axis (or tuple of axes) inside shard_map.
-
-    ``jax.lax.axis_size`` only exists in newer jax; ``psum(1, axis)``
-    constant-folds to the same Python int everywhere.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+    """Static size of a named mesh axis (or tuple of axes) inside shard_map."""
+    return jax.lax.axis_size(axis)
 
 
 __all__ = ["shard_map", "SM_CHECK_KW", "axis_size"]

@@ -527,7 +527,13 @@ def stage2_cam_match(
     vals = jnp.where(valid, vals, jnp.zeros((), activity.dtype))
     if syn_onehot is None:
         syn_onehot = precompute_syn_onehot(cam_syn, dtype=vals.dtype)
-    out = jnp.einsum("...ns,nst->...nt", vals, syn_onehot.astype(vals.dtype))
+    # HIGHEST: a TPU would otherwise round the f32 operands to bf16
+    out = jnp.einsum(
+        "...ns,nst->...nt",
+        vals,
+        syn_onehot.astype(vals.dtype),
+        precision=jax.lax.Precision.HIGHEST,
+    )
     return out.reshape(*batch_shape, n, N_SYN_TYPES)
 
 

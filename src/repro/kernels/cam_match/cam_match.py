@@ -20,8 +20,11 @@ without growing the VMEM-resident CAM state. All events of a timestep that
 target one core are therefore resolved against VMEM-resident state, exactly
 the paper's "CAM cells of different cores operate in parallel" argument.
 
-Block shapes: K and S should be multiples of 128 on real hardware for MXU
-alignment; interpret mode (CPU validation) accepts any shape.
+Block layout: the activity is viewed (for free) as ``[B, 1, nc * K]`` so
+each grid step's block ``(1, 1, K)`` has a full second-minor dim and a
+lane dim of K — the TPU's (8, 128) tiling rule then only needs
+``K % 128 == 0``, which the compiled path checks (interpret mode, the CPU
+validation path, accepts any shape).
 """
 
 from __future__ import annotations
@@ -35,17 +38,42 @@ from jax.experimental import pallas as pl
 
 N_SYN_TYPES = 4
 
+# stage-1 compare-plane budget of the fused kernels: ev_chunk * K floats
+# kept under ~2 MB of VMEM
+_PLANE_BUDGET_ELEMS = 512 * 1024
 
-def _cam_match_kernel(activity_ref, tag_ref, syn_ref, out_ref, *, k_tags: int):
-    # activity_ref: [1, 1, K]     — this (batch, cluster)'s broadcast activity
-    # tag_ref:      [1, Cb, S]    — CAM tags of the neuron tile (batch-shared)
-    # syn_ref:      [1, Cb, S]    — synapse types of the neuron tile
-    # out_ref:      [1, 1, Cb, 4] — per-type synaptic drive
-    a = activity_ref[0, 0, :]  # [K]
-    tags = tag_ref[0]  # [Cb, S] int32
-    syn = syn_ref[0]  # [Cb, S] int32
+
+def event_chunk(n_entries: int, k_tags: int) -> int:
+    """Entries per stage-1 compare plane (fused and fabric kernels).
+
+    The whole entry axis when it fits the plane budget, else the largest
+    multiple of 128 lanes that does, so every in-kernel ``pl.ds`` chunk
+    starts lane-aligned.
+    """
+    fit = _PLANE_BUDGET_ELEMS // max(1, k_tags)
+    if n_entries <= fit:
+        return max(1, n_entries)
+    return max(128, fit // 128 * 128)
+
+
+def check_lane_aligned(k_tags: int, interpret: bool) -> None:
+    """The compiled kernels tile a cluster's K-row as one lane block, so K
+    must be a multiple of the TPU's 128 lanes; interpret mode takes any K."""
+    if not interpret and k_tags % 128:
+        raise ValueError(
+            f"the compiled Pallas delivery kernels need K % 128 == 0, got "
+            f"K={k_tags}; pad the tag space or run with interpret=True"
+        )
+
+
+def cam_drive(a: jax.Array, tags: jax.Array, syn: jax.Array) -> jax.Array:
+    """Stage-2 CAM match of one VMEM-resident activity row against a neuron
+    tile: ``a [K]``, ``tags``/``syn [Cb, S]`` -> drive ``[Cb, 4]`` (f32).
+
+    Shared by the cam_match, fused_deliver and fabric_deliver kernel bodies.
+    """
+    k_tags = a.shape[0]
     cb, s = tags.shape
-
     valid = tags >= 0
     # CAM compare plane: [Cb, S, K] one-hot (the parallel match-line search).
     kk = jax.lax.broadcasted_iota(jnp.int32, (cb, s, k_tags), 2)
@@ -61,12 +89,20 @@ def _cam_match_kernel(activity_ref, tag_ref, syn_ref, out_ref, *, k_tags: int):
     # accumulate into the 4 synapse-type lines (pulse-decoder DECs).
     tt = jax.lax.broadcasted_iota(jnp.int32, (cb, s, N_SYN_TYPES), 2)
     syn1h = (syn[:, :, None] == tt).astype(vals.dtype)
-    drive = jax.lax.dot_general(
+    return jax.lax.dot_general(
         vals.reshape(cb, 1, s),
         syn1h,
         (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     ).reshape(cb, N_SYN_TYPES)
+
+
+def _cam_match_kernel(activity_ref, tag_ref, syn_ref, out_ref):
+    # activity_ref: [1, 1, K]     — this (batch, cluster)'s broadcast activity
+    # tag_ref:      [1, Cb, S]    — CAM tags of the neuron tile (batch-shared)
+    # syn_ref:      [1, Cb, S]    — synapse types of the neuron tile
+    # out_ref:      [1, 1, Cb, 4] — per-type synaptic drive
+    drive = cam_drive(activity_ref[0, 0, :], tag_ref[0], syn_ref[0])
     out_ref[0, 0] = drive.astype(out_ref.dtype)
 
 
@@ -86,17 +122,18 @@ def cam_match_pallas(
     assert n == n_clusters * cluster_size
     block_c = min(block_c, cluster_size)
     assert cluster_size % block_c == 0, (cluster_size, block_c)
+    check_lane_aligned(k, interpret)
 
-    act3 = activity.reshape(b, n_clusters, k)
+    act3 = activity.reshape(b, 1, n_clusters * k)
     tags3 = cam_tag.reshape(n_clusters, cluster_size, s)
     syn3 = cam_syn.reshape(n_clusters, cluster_size, s)
     grid = (b, n_clusters, cluster_size // block_c)
 
     out = pl.pallas_call(
-        functools.partial(_cam_match_kernel, k_tags=k),
+        _cam_match_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, k), lambda bi, i, j: (bi, i, 0)),
+            pl.BlockSpec((1, 1, k), lambda bi, i, j: (bi, 0, i)),
             pl.BlockSpec((1, block_c, s), lambda bi, i, j: (i, j, 0)),
             pl.BlockSpec((1, block_c, s), lambda bi, i, j: (i, j, 0)),
         ],

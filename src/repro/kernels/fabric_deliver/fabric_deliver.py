@@ -28,7 +28,8 @@ persists for the (batch, cluster) pair's remaining neuron tiles, and the
 ring column written once at ``j == 0`` is flushed when the block changes.
 
 VMEM sizing: the compare plane is chunked to ``ev_chunk * K`` floats under
-``_PLANE_BUDGET_ELEMS`` (one plane per delay slot is built at a time); the
+the budget of ``cam_match.event_chunk`` (one plane per delay slot is built
+at a time); the
 resident ring column adds ``(max_delay + 1) * K`` floats and the scratch
 row ``K`` — small next to the plane budget for any realistic ``max_delay``.
 """
@@ -43,22 +44,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-N_SYN_TYPES = 4
-
-# compare-plane budget: ev_chunk * K floats kept under ~2 MB of VMEM
-_PLANE_BUDGET_ELEMS = 512 * 1024
+from repro.kernels.cam_match.cam_match import (
+    N_SYN_TYPES,
+    cam_drive,
+    check_lane_aligned,
+    event_chunk,
+)
 
 
 def _fabric_deliver_kernel(
     cur_ref,  # SMEM [1, 1] int32 — the time-wheel write cursor
     ev_flat_ref,  # [1, Mp] int32 — flat ring target per entry (-1 = pad)
-    ev_w_ref,  # [1, Mp] — masked event weight (0 = dropped/silent/pad)
+    ev_w_ref,  # [1, 1, Mp] — masked event weight (0 = dropped/silent/pad)
     ext_ref,  # [1, 1, K] — external input activity for this (batch, cluster)
-    ring_ref,  # [1, D1, 1, K] — carried ring column of this (batch, cluster)
+    ring_ref,  # [1, D1, K] — carried ring column of this (batch, cluster)
     tag_ref,  # [1, Cb, S] — CAM tags of the neuron tile (batch-shared)
     syn_ref,  # [1, Cb, S] — synapse types of the neuron tile
     out_ref,  # [1, 1, Cb, 4] — per-type synaptic drive
-    ring_out_ref,  # [1, D1, 1, K] — updated ring column (cursor row zeroed)
+    ring_out_ref,  # [1, D1, K] — updated ring column (cursor row zeroed)
     act_ref,  # VMEM scratch [1, K] — this (batch, cluster)'s arrival row
     *,
     k_tags: int,
@@ -75,8 +78,9 @@ def _fabric_deliver_kernel(
         mp = ev_flat_ref.shape[1]
 
         def chunk_body(i, col):
-            f = ev_flat_ref[0, pl.ds(i * ev_chunk, ev_chunk)]  # [ev_chunk]
-            w = ev_w_ref[0, pl.ds(i * ev_chunk, ev_chunk)]
+            at = pl.ds(pl.multiple_of(i * ev_chunk, ev_chunk), ev_chunk)
+            f = ev_flat_ref[0, at]  # [ev_chunk]
+            w = ev_w_ref[0, 0, at]
             rows = []
             for d in range(d1):  # static, small: one compare plane per slot
                 base = (d * n_clusters + c) * k_tags
@@ -96,7 +100,7 @@ def _fabric_deliver_kernel(
             return col + jnp.concatenate(rows, axis=0)  # [D1, K]
 
         col = jax.lax.fori_loop(
-            0, mp // ev_chunk, chunk_body, ring_ref[0, :, 0, :].astype(jnp.float32)
+            0, mp // ev_chunk, chunk_body, ring_ref[0].astype(jnp.float32)
         )
         # pop the cursor slot: arrivals = carried + zero-delay + external,
         # then clear the row so the wheel can reuse it next revolution
@@ -105,34 +109,10 @@ def _fabric_deliver_kernel(
         act_ref[0, :] = (arrivals + ext_ref[0, 0, :].astype(jnp.float32)).astype(
             act_ref.dtype
         )
-        ring_out_ref[0, :, 0, :] = jnp.where(sel, 0.0, col).astype(
-            ring_out_ref.dtype
-        )
+        ring_out_ref[0] = jnp.where(sel, 0.0, col).astype(ring_out_ref.dtype)
 
-    # stage 2: CAM match of the VMEM-resident arrival row (kernels/fused_deliver)
-    a = act_ref[0, :]  # [K]
-    tags = tag_ref[0]  # [Cb, S] int32
-    syn = syn_ref[0]  # [Cb, S] int32
-    cb, s = tags.shape
-
-    valid = tags >= 0
-    kk = jax.lax.broadcasted_iota(jnp.int32, (cb, s, k_tags), 2)
-    match = (tags[:, :, None] == kk).astype(a.dtype)
-    vals = jax.lax.dot_general(
-        match.reshape(cb * s, k_tags),
-        a.reshape(k_tags, 1),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(cb, s)
-    vals = jnp.where(valid, vals, 0.0)
-    tt = jax.lax.broadcasted_iota(jnp.int32, (cb, s, N_SYN_TYPES), 2)
-    syn1h = (syn[:, :, None] == tt).astype(vals.dtype)
-    drive = jax.lax.dot_general(
-        vals.reshape(cb, 1, s),
-        syn1h,
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ).reshape(cb, N_SYN_TYPES)
+    # stage 2: CAM match of the VMEM-resident arrival row (kernels/cam_match)
+    drive = cam_drive(act_ref[0, :], tag_ref[0], syn_ref[0])
     out_ref[0, 0] = drive.astype(out_ref.dtype)
 
 
@@ -162,23 +142,28 @@ def fabric_deliver_ring_pallas(
     b = math.prod(batch_shape)
     block_c = min(block_c, cluster_size)
     assert cluster_size % block_c == 0, (cluster_size, block_c)
+    check_lane_aligned(k, interpret)
     dtype = ev_w.dtype
 
-    ev_w2 = ev_w.reshape(b, -1)
-    m = ev_w2.shape[1]
+    # free views that keep every block's last two dims full or (D1|1, K) —
+    # the TPU's (8, 128) tiling rule (cam_match.py): weights [B, 1, M],
+    # external input [B, 1, nc * K], and the ring [B, D1, nc * K], whose
+    # (b, c) column is the block (1, D1, K) at lane-block c
+    ev_w2 = ev_w.reshape(b, 1, -1)
+    m = ev_w2.shape[2]
     # chunk the compare plane to a fixed VMEM budget; pad M up so the chunks
     # tile it exactly (padding entries are -1/0 = no-ops)
-    ev_chunk = max(1, min(m, _PLANE_BUDGET_ELEMS // max(1, k)))
+    ev_chunk = event_chunk(m, k)
     m_pad = -(-m // ev_chunk) * ev_chunk
     ev_flat2 = ev_flat.reshape(1, m)
     if m_pad != m:
         ev_flat2 = jnp.pad(ev_flat2, ((0, 0), (0, m_pad - m)), constant_values=-1)
-        ev_w2 = jnp.pad(ev_w2, ((0, 0), (0, m_pad - m)))
+        ev_w2 = jnp.pad(ev_w2, ((0, 0), (0, 0), (0, m_pad - m)))
 
-    ring2 = ring.reshape(b, d1, n_clusters, k)
+    ring2 = ring.reshape(b, d1, n_clusters * k)
     ext3 = jnp.broadcast_to(
         external_activity, (*batch_shape, n_clusters, k)
-    ).reshape(b, n_clusters, k).astype(dtype)
+    ).reshape(b, 1, n_clusters * k).astype(dtype)
     tags3 = cam_tag.reshape(n_clusters, cluster_size, s)
     syn3 = cam_syn.reshape(n_clusters, cluster_size, s)
     cur2 = jnp.asarray(cursor, jnp.int32).reshape(1, 1)
@@ -196,19 +181,19 @@ def fabric_deliver_ring_pallas(
         in_specs=[
             pl.BlockSpec((1, 1), lambda bi, i, j: (0, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((1, m_pad), lambda bi, i, j: (0, 0)),
-            pl.BlockSpec((1, m_pad), lambda bi, i, j: (bi, 0)),
-            pl.BlockSpec((1, 1, k), lambda bi, i, j: (bi, i, 0)),
-            pl.BlockSpec((1, d1, 1, k), lambda bi, i, j: (bi, 0, i, 0)),
+            pl.BlockSpec((1, 1, m_pad), lambda bi, i, j: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, k), lambda bi, i, j: (bi, 0, i)),
+            pl.BlockSpec((1, d1, k), lambda bi, i, j: (bi, 0, i)),
             pl.BlockSpec((1, block_c, s), lambda bi, i, j: (i, j, 0)),
             pl.BlockSpec((1, block_c, s), lambda bi, i, j: (i, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_c, N_SYN_TYPES), lambda bi, i, j: (bi, i, j, 0)),
-            pl.BlockSpec((1, d1, 1, k), lambda bi, i, j: (bi, 0, i, 0)),
+            pl.BlockSpec((1, d1, k), lambda bi, i, j: (bi, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, n_clusters, cluster_size, N_SYN_TYPES), dtype),
-            jax.ShapeDtypeStruct((b, d1, n_clusters, k), ring.dtype),
+            jax.ShapeDtypeStruct((b, d1, n_clusters * k), ring.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((1, k), dtype)],
         interpret=interpret,

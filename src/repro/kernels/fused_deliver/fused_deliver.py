@@ -33,15 +33,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-N_SYN_TYPES = 4
-
-# stage-1 compare-plane budget: ev_chunk * K floats kept under ~2 MB of VMEM
-_PLANE_BUDGET_ELEMS = 512 * 1024
+from repro.kernels.cam_match.cam_match import (
+    N_SYN_TYPES,
+    cam_drive,
+    check_lane_aligned,
+    event_chunk,
+)
 
 
 def _fused_deliver_kernel(
-    ev_flat_ref,  # [1, QE] int32 — flat (dest*K + tag) per queued entry, -1 empty
-    ev_w_ref,  # [1, QE] — event weight per entry (0 for empty)
+    ev_flat_ref,  # [1, 1, QE] int32 — flat (dest*K + tag) per queued entry, -1 empty
+    ev_w_ref,  # [1, 1, QE] — event weight per entry (0 for empty)
     ext_ref,  # [1, 1, K] — external input activity for this (batch, cluster)
     tag_ref,  # [1, Cb, S] — CAM tags of the neuron tile (batch-shared)
     syn_ref,  # [1, Cb, S] — synapse types of the neuron tile
@@ -58,11 +60,12 @@ def _fused_deliver_kernel(
     def _build_activity_row():
         # stage 1 for (b, c): accumulate this cluster's K-row from the queue.
         base = c * k_tags
-        qe = ev_flat_ref.shape[1]
+        qe = ev_flat_ref.shape[2]
 
         def chunk_body(i, acc):
-            f = ev_flat_ref[0, pl.ds(i * ev_chunk, ev_chunk)]  # [ev_chunk]
-            w = ev_w_ref[0, pl.ds(i * ev_chunk, ev_chunk)]
+            at = pl.ds(pl.multiple_of(i * ev_chunk, ev_chunk), ev_chunk)
+            f = ev_flat_ref[0, 0, at]  # [ev_chunk]
+            w = ev_w_ref[0, 0, at]
             kk = jax.lax.broadcasted_iota(jnp.int32, (ev_chunk, k_tags), 1) + base
             match = (f[:, None] == kk).astype(acc.dtype)  # [ev_chunk, K]
             return acc + jax.lax.dot_general(
@@ -78,29 +81,7 @@ def _fused_deliver_kernel(
         act_ref[...] = row.astype(act_ref.dtype)
 
     # stage 2: CAM match of the VMEM-resident row against this neuron tile.
-    a = act_ref[0, :]  # [K]
-    tags = tag_ref[0]  # [Cb, S] int32
-    syn = syn_ref[0]  # [Cb, S] int32
-    cb, s = tags.shape
-
-    valid = tags >= 0
-    kk = jax.lax.broadcasted_iota(jnp.int32, (cb, s, k_tags), 2)
-    match = (tags[:, :, None] == kk).astype(a.dtype)
-    vals = jax.lax.dot_general(
-        match.reshape(cb * s, k_tags),
-        a.reshape(k_tags, 1),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(cb, s)
-    vals = jnp.where(valid, vals, 0.0)
-    tt = jax.lax.broadcasted_iota(jnp.int32, (cb, s, N_SYN_TYPES), 2)
-    syn1h = (syn[:, :, None] == tt).astype(vals.dtype)
-    drive = jax.lax.dot_general(
-        vals.reshape(cb, 1, s),
-        syn1h,
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    ).reshape(cb, N_SYN_TYPES)
+    drive = cam_drive(act_ref[0, :], tag_ref[0], syn_ref[0])
     out_ref[0, 0] = drive.astype(out_ref.dtype)
 
 
@@ -125,23 +106,26 @@ def fused_deliver_pallas(
     b = math.prod(batch_shape)
     block_c = min(block_c, cluster_size)
     assert cluster_size % block_c == 0, (cluster_size, block_c)
+    check_lane_aligned(k, interpret)
     dtype = ev_w.dtype
 
-    ev_flat2 = ev_flat.reshape(b, -1)
-    ev_w2 = ev_w.reshape(b, -1)
-    qe = ev_flat2.shape[1]
+    # free [B, 1, QE] / [B, 1, nc * K] views: every block's last two dims are
+    # then full or (1, K) — the TPU's (8, 128) tiling rule (cam_match.py)
+    ev_flat2 = ev_flat.reshape(b, 1, -1)
+    ev_w2 = ev_w.reshape(b, 1, -1)
+    qe = ev_flat2.shape[2]
     # chunk the stage-1 compare plane to a fixed VMEM budget; pad QE up so
     # the chunks tile it exactly (padding entries are -1/0 = no-ops).
-    ev_chunk = max(1, min(qe, _PLANE_BUDGET_ELEMS // max(1, k)))
+    ev_chunk = event_chunk(qe, k)
     qe_pad = -(-qe // ev_chunk) * ev_chunk
     if qe_pad != qe:
-        pad = ((0, 0), (0, qe_pad - qe))
+        pad = ((0, 0), (0, 0), (0, qe_pad - qe))
         ev_flat2 = jnp.pad(ev_flat2, pad, constant_values=-1)
         ev_w2 = jnp.pad(ev_w2, pad)
 
     ext3 = jnp.broadcast_to(
         external_activity, (*batch_shape, n_clusters, k)
-    ).reshape(b, n_clusters, k).astype(dtype)
+    ).reshape(b, 1, n_clusters * k).astype(dtype)
     tags3 = cam_tag.reshape(n_clusters, cluster_size, s)
     syn3 = cam_syn.reshape(n_clusters, cluster_size, s)
     grid = (b, n_clusters, cluster_size // block_c)
@@ -150,9 +134,9 @@ def fused_deliver_pallas(
         functools.partial(_fused_deliver_kernel, k_tags=k, ev_chunk=ev_chunk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, qe_pad), lambda bi, i, j: (bi, 0)),
-            pl.BlockSpec((1, qe_pad), lambda bi, i, j: (bi, 0)),
-            pl.BlockSpec((1, 1, k), lambda bi, i, j: (bi, i, 0)),
+            pl.BlockSpec((1, 1, qe_pad), lambda bi, i, j: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, qe_pad), lambda bi, i, j: (bi, 0, 0)),
+            pl.BlockSpec((1, 1, k), lambda bi, i, j: (bi, 0, i)),
             pl.BlockSpec((1, block_c, s), lambda bi, i, j: (i, j, 0)),
             pl.BlockSpec((1, block_c, s), lambda bi, i, j: (i, j, 0)),
         ],

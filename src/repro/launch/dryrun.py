@@ -1,9 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 " + os.environ.get("XLA_FLAGS", "")
-)
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this driver:
@@ -24,6 +18,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import re
 import time
 import traceback
@@ -307,8 +302,6 @@ def run_cell(arch: str, shape: Shape, multi_pod: bool, opt_cfg: OptConfig | None
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per computation
-        cost = cost[0] if cost else None
     hlo = compiled.as_text()
     coll_hlo = hlo_collective_bytes(hlo)
     # analytic (scan-aware) cost from the traced jaxpr
@@ -423,4 +416,10 @@ def main():
 
 
 if __name__ == "__main__":
+    # the production meshes span 512 fake host devices; XLA reads the flag
+    # when the backend first starts, which no import above does
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("XLA_FLAGS", "")
+    )
     main()

@@ -62,6 +62,7 @@ __all__ = [
     "CheckpointMismatchError",
     "build_poker_engine",
     "session_from_meta",
+    "table_v_models",
 ]
 
 
@@ -183,6 +184,46 @@ def build_poker_engine(
         tables, params, backend=backend, queue_capacity=q_cap,
         donate_carry=donate_carry, autotune=autotune,
     )
+
+
+def table_v_models(rng: np.random.Generator) -> dict[str, CompiledCnn]:
+    """The served Table-V network, resident twice (DESIGN.md §16).
+
+    The readout is tuned by one offline-Hebbian calibration run (paper §V)
+    on streams drawn from ``rng``. ``"tableV-3x3"`` keeps the 3x3-chip board
+    placement; ``"tableV-2x2"`` is the same network bound to a 2x2-chip
+    geometry (2 cores per chip, the smallest mesh its 6 cores fit). That
+    retarget only re-places the tables — the CNN's spliced input taps live
+    in the CAM words — and the resident copy is stripped back to the
+    fabric default, since placements compose all-or-none across residents
+    (DESIGN.md §18).
+    """
+    from repro.core.cnn import CnnConfig, compile_poker_cnn, hebbian_readout_select
+    from repro.core.compiler import Geometry, artifact_from_tables
+    from repro.data.pipeline import symbol_dvs_events
+
+    cc = compile_poker_cnn()
+    eng = EventEngine(cc.tables, poker_neuron_params())
+    t_steps, reps = 40, 3
+    streams = [
+        symbol_dvs_events(sym, 400, rng) for sym in range(4) for _ in range(reps)
+    ]
+    act = cc.input_activity_batch(streams) / t_steps * 10.0
+    inp = np.broadcast_to(act[None], (t_steps, *act.shape))
+    _, spikes = eng.run(eng.init_state(batch=len(streams)), inp)
+    pool_rates = (
+        np.asarray(spikes)[:, :, cc.pool[0] : cc.pool[1]]
+        .sum(0)
+        .reshape(4, reps, -1)
+        .sum(1)
+    )
+    cc = compile_poker_cnn(CnnConfig(), fc_select=hebbian_readout_select(pool_rates))
+    geo2 = Geometry(grid_x=2, grid_y=2, cores_per_tile=2, neurons_per_core=256)
+    art2 = artifact_from_tables(cc.tables, geo2, optimize=False)
+    cc2 = dataclasses.replace(
+        cc, tables=dataclasses.replace(art2.tables, tile_of_cluster=None)
+    )
+    return {"tableV-3x3": cc, "tableV-2x2": cc2}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -315,7 +356,8 @@ class AerSessionPool:
         In fabric-ring mode the static entry table is assembled slab-by-slab
         (slab-offset addressing); fault injection needs the full-grid
         Bernoulli draw, so faulted engines build from the concatenated table
-        instead — the two constructions are bit-identical.
+        instead — the two constructions are bit-identical. The roll-carried
+        fabric path (``fabric_options={"ring": False}``) has no entry table.
         """
         registry = ModelRegistry(
             {name: m.tables for name, m in models.items()}
@@ -326,6 +368,7 @@ class AerSessionPool:
             len(models) > 1
             and engine_kw.get("backend") == "fabric"
             and engine_kw.get("faults") is None
+            and (engine_kw.get("fabric_options") or {}).get("ring", True)
         ):
             entry_slabs = [
                 (t.src_tag, t.src_dest)
@@ -708,6 +751,15 @@ class AerSessionPool:
         asynchronous, so this returns as soon as the step is enqueued on the
         device — nothing here blocks on the result.
         """
+        self.carry, out = self.engine.step(self.carry, self.gather_inputs())
+        return out
+
+    def gather_inputs(self) -> np.ndarray:
+        """This step's external tag activity ``[P, nc_total, K_max]`` (host).
+
+        Each occupied slot's stream events at the session's own step,
+        placed in its model's slab; vacant and faulted slots get zeros.
+        """
         multi = len(self.models) > 1
         acts = []
         for sess in self.slots:
@@ -736,9 +788,7 @@ class AerSessionPool:
                     slab.cluster_lo : slab.cluster_hi, : slab.k_tags
                 ] = a * self.cfg.drive
                 acts.append(full)
-        inp = np.stack(acts)  # [P, nc_total, K_max]
-        self.carry, out = self.engine.step(self.carry, inp)
-        return out
+        return np.stack(acts)
 
     def finish_step(self, out) -> np.ndarray:
         """Block on a dispatched step's results and apply them per session."""

@@ -20,6 +20,9 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(body: str, timeout=600):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    # fake CPU devices; the child never reaches for an accelerator, which
+    # the parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(body)],

@@ -175,6 +175,24 @@ def test_fabric_multimodel_prediction_parity():
         assert r_solo[sid].prediction == r_multi[sid].prediction
 
 
+def test_fabric_roll_multimodel_pool_matches_ring():
+    """The roll-carried fabric path has no static entry table: a 2-model
+    pool on it builds and decides exactly as the ring fast path does."""
+    cc = _poker_cc()
+    got = {}
+    for ring in (True, False):
+        pool = AerSessionPool.from_models(
+            {"a": cc, "b": cc}, _cfg(), backend="fabric",
+            fabric_options={"ring": ring},
+        )
+        assert pool.engine.fabric_ring is ring
+        got[ring] = {
+            r.session_id: (r.prediction, r.latency_steps, r.counts.tolist())
+            for r in pool.serve([_session(0, 1, "a"), _session(1, 2, "b")])
+        }
+    assert got[True] == got[False]
+
+
 def test_admit_requires_model_name_when_ambiguous():
     cc = _poker_cc()
     pool = AerSessionPool.from_models({"a": cc, "b": cc}, _cfg())

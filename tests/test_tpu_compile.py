@@ -1,0 +1,159 @@
+"""Compile rehearsal for a TPU v5e chip, with no chip attached.
+
+The three delivery kernels and the jitted serving-pool step are compiled by
+the TPU compiler for a described v5e device at the served Table-V shapes
+(pool of 32 slots; 6 clusters for one resident model, 12 for two; 256
+neurons per cluster, K = 1024 tags, 64 CAM words). Interpret mode accepts
+block layouts and VMEM footprints the chip refuses; these compiles do not.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, so a description made while pytest
+collects would break the other workers. The tests skip where no v5e
+topology can be described.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.cam_match.cam_match import cam_match_pallas
+from repro.kernels.fabric_deliver.fabric_deliver import fabric_deliver_ring_pallas
+from repro.kernels.fused_deliver.fused_deliver import fused_deliver_pallas
+
+POOL, C, K, S, E = 32, 256, 1024, 64, 16
+MAX_DELAY = 1  # the served fabric's delay horizon
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            desc = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # programs compiled for a described chip cannot be read back from
+        # the persistent cache without the chip: keep them out of it
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield desc
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes):
+    lowered = jax.jit(fn).lower(*shapes)
+    text = lowered.as_text()
+    compiled = lowered.compile()
+    return text, compiled
+
+
+def _kernel_call(kernel: str, nc: int, sds):
+    n = nc * C
+    tags = sds((n, S), jnp.int32)
+    if kernel == "cam_match":
+        return (
+            lambda a, t, s: cam_match_pallas(a, t, s, C, interpret=False),
+            sds((POOL, nc, K)), tags, tags,
+        )
+    if kernel == "fused_deliver":
+        qe = n * E  # lossless AER queue: every neuron, every SRAM entry
+        return (
+            lambda f, w, t, s, x: fused_deliver_pallas(
+                f, w, t, s, x, C, K, interpret=False
+            ),
+            sds((POOL, qe), jnp.int32), sds((POOL, qe)), tags, tags,
+            sds((POOL, nc, K)),
+        )
+    m = n  # occupied SRAM entries: about one per neuron in the Table-V CNN
+    return (
+        lambda f, w, r, c, x, t, s: fabric_deliver_ring_pallas(
+            f, w, r, c, x, t, s, C, K, MAX_DELAY, interpret=False
+        ),
+        sds((m,), jnp.int32), sds((POOL, m)),
+        sds((POOL, MAX_DELAY + 1, nc, K)), sds((), jnp.int32),
+        sds((POOL, nc, K)), tags, tags,
+    )
+
+
+@pytest.mark.parametrize("nc", [6, 12])
+@pytest.mark.parametrize("kernel", ["cam_match", "fused_deliver", "fabric_deliver"])
+def test_kernel_compiles_for_v5e(one_chip, kernel, nc):
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, *shapes = _kernel_call(kernel, nc, sds)
+    text, compiled = _compile(fn, *shapes)
+    assert "tpu_custom_call" in text
+    assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("kernel", ["cam_match", "fused_deliver", "fabric_deliver"])
+def test_compiled_kernel_refuses_unaligned_k(kernel):
+    """The compiled path tiles K as one lane block; K % 128 != 0 is refused
+    before lowering, on any platform (interpret mode keeps any K)."""
+    nc, k = 2, 24
+    n = nc * C
+    tags = jnp.zeros((n, S), jnp.int32)
+    act = jnp.zeros((1, nc, k))
+    with pytest.raises(ValueError, match="K % 128"):
+        if kernel == "cam_match":
+            cam_match_pallas(act, tags, tags, C, interpret=False)
+        elif kernel == "fused_deliver":
+            ev = jnp.zeros((1, 8), jnp.int32)
+            fused_deliver_pallas(ev, ev.astype(jnp.float32), tags, tags, act,
+                                 C, k, interpret=False)
+        else:
+            ev = jnp.zeros((8,), jnp.int32)
+            ring = jnp.zeros((1, MAX_DELAY + 1, nc, k))
+            fabric_deliver_ring_pallas(
+                ev, jnp.zeros((1, 8)), ring, jnp.int32(0), act, tags, tags,
+                C, k, MAX_DELAY, interpret=False,
+            )
+
+
+@pytest.mark.parametrize("backend", ["pallas", "fused", "fabric"])
+def test_two_model_pool_step_compiles_for_v5e(one_chip, backend):
+    """The jitted step of the served 2-model pool (nc = 12), with the
+    backend's kernel forced to its compiled form: the platform policy would
+    pick the jnp reference on this CPU-only host."""
+    from repro.core.cnn import compile_poker_cnn
+    from repro.core.dispatch import FusedBackend, PallasBackend
+    from repro.serve.aer import AerServeConfig, AerSessionPool
+
+    cc = compile_poker_cnn()
+    kw = {
+        "pallas": {"backend": PallasBackend(interpret=False)},
+        "fused": {"backend": FusedBackend(interpret=False)},
+        "fabric": {"backend": "fabric", "fabric_options": {"interpret": False}},
+    }[backend]
+    pool = AerSessionPool.from_models(
+        {"a": cc, "b": cc}, AerServeConfig(pool_size=POOL),
+        **kw,
+    )
+    eng = pool.engine
+    assert eng.n_clusters == 12 and eng.k_tags == K
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    carry = jax.tree.map(sds, pool.carry)
+    inp = jax.ShapeDtypeStruct((POOL, eng.n_clusters, K), jnp.float32,
+                               sharding=one_chip)
+    text, compiled = _compile(eng.step, carry, inp)
+    assert "tpu_custom_call" in text
+    assert compiled.memory_analysis() is not None

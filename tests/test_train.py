@@ -117,7 +117,8 @@ def test_file_source_roundtrip(tmp_path):
 # ---------------------------------------------------------------------------
 def test_supervisor_restart_after_injected_failure(tmp_path):
     """End-to-end fault tolerance: crash at step 15, resume from ckpt 10."""
-    env = dict(os.environ, PYTHONPATH="src")
+    # the child trains on the CPU: an accelerator belongs to one process
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch", "gemma3-1b", "--smoke",
          "--steps", "20", "--batch", "2", "--seq", "16", "--ckpt-dir", str(tmp_path),
