@@ -12,10 +12,6 @@
 
 Prints ``name,us_per_call,derived`` CSV and writes the routing/dispatch rows
 to ``BENCH_routing.json`` (machine-readable perf trajectory across PRs).
-
-``--profile [DIR]`` wraps the whole sweep in a ``jax.profiler`` trace
-(default ``/tmp/repro_bench_trace``) — open the directory with
-TensorBoard / Perfetto to see per-kernel timings behind any row.
 """
 
 from __future__ import annotations
@@ -34,14 +30,6 @@ _ROUTING_MODULES = ("routing_throughput", "dispatch", "serving")
 
 def main(argv: list[str] | None = None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument(
-        "--profile",
-        nargs="?",
-        const="/tmp/repro_bench_trace",
-        default=None,
-        metavar="DIR",
-        help="capture a jax.profiler trace of the sweep into DIR",
-    )
     ap.add_argument(
         "--devices",
         type=int,
@@ -64,15 +52,7 @@ def main(argv: list[str] | None = None) -> None:
     if args.devices is not None:
         fake_host_devices(args.devices)
     enable_compile_cache()
-    only = args.only.split(",") if args.only else None
-    if args.profile is not None:
-        import jax
-
-        with jax.profiler.trace(args.profile):
-            _run_all(only)
-        print(f"wrote profiler trace to {args.profile}", file=sys.stderr)
-    else:
-        _run_all(only)
+    _run_all(args.only.split(",") if args.only else None)
 
 
 def _run_all(only: list[str] | None = None) -> None:

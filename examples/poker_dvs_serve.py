@@ -101,6 +101,7 @@ def main():
     dropped = sum(r.dropped for r in results)
     linkd = sum(r.link_dropped for r in results)
     print(f"event loss: {dropped} AER-queue drops, {linkd} link-FIFO drops")
+    print("pool counters: " + ", ".join(f"{k} {v}" for k, v in pool.counters().items()))
 
 
 if __name__ == "__main__":

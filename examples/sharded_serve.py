@@ -92,6 +92,7 @@ def main() -> None:
     if stats is not None and stats.delivered is not None:
         print(f"  fleet last-step delivery: {int(stats.delivered)} events, "
               f"{int(stats.link_dropped or 0)} link drops")
+    print("  fleet counters: " + ", ".join(f"{k} {v}" for k, v in fleet.counters().items()))
 
     # -- live migration: drain a shard under load ---------------------------
     # one tenant per shard, so the rest of the fleet always has room

@@ -287,6 +287,7 @@ class PallasBackend(DispatchBackend):
     block_c: int = 16
     interpret: bool | None = None
 
+    @jax.named_scope("stage2")
     def cam_match(self, activity, cam_tag, cam_syn, cluster_size, syn_onehot=None):
         # the kernel builds its compare planes in-register; the precomputed
         # one-hot is a jnp-path optimization and is ignored here.

@@ -306,6 +306,11 @@ class EventEngine:
         donate = _donate_carry_kwargs() if donate_carry else {}
         self._jit_step = jax.jit(self._step_impl, **donate)
         self._jit_reset = jax.jit(self._reset_impl)
+        # Python-body traces of the jitted step and reset: each is a
+        # compilation, so a count that moves while serving names the program
+        # that recompiled
+        self.step_traces = 0
+        self.reset_traces = 0
 
     # ------------------------------------------------------------------
     def init_state(
@@ -356,7 +361,14 @@ class EventEngine:
         """
         return self._jit_step(carry, input_activity, i_ext)
 
+    def compiled_step_text(self, carry, input_activity) -> str:
+        """The compiled step's optimized HLO for these carry and input
+        shapes (arrays or ``jax.ShapeDtypeStruct``s): its instructions as a
+        profiler trace names them, each with its scope path (``op_name``)."""
+        return self._jit_step.lower(carry, input_activity).compile().as_text()
+
     def _step_impl(self, carry, input_activity, i_ext=None):
+        self.step_traces += 1
         # inputs adopt the carry dtype: under x64, default-f64 stimulus
         # arrays would otherwise promote the neuron state mid-scan and trip
         # lax.scan's carry-type check
@@ -366,24 +378,47 @@ class EventEngine:
             i_ext = jnp.asarray(i_ext, dtype)
         if self.fabric_backend is not None and self.fabric_ring:
             state, prev_spikes, ring, cursor = carry
-            drive, ring, cursor, stats = self.fabric_backend.deliver_fabric_ring(
-                prev_spikes,
-                self._fabric_entries,
-                self.tables.cam_tag,
-                self.tables.cam_syn,
-                self.cluster_size,
-                self.k_tags,
-                ring,
-                cursor,
-                external_activity=input_activity,
-                queue_capacity=self.queue_capacity,
-                syn_onehot=self.tables.cam_syn_onehot,
-            )
+            with jax.named_scope("deliver"):
+                drive, ring, cursor, stats = self.fabric_backend.deliver_fabric_ring(
+                    prev_spikes,
+                    self._fabric_entries,
+                    self.tables.cam_tag,
+                    self.tables.cam_syn,
+                    self.cluster_size,
+                    self.k_tags,
+                    ring,
+                    cursor,
+                    external_activity=input_activity,
+                    queue_capacity=self.queue_capacity,
+                    syn_onehot=self.tables.cam_syn_onehot,
+                )
             state, spikes = neuron_mod.neuron_step(state, drive, self.params, i_ext)
             return (state, spikes, ring, cursor), (spikes, stats)
         if self.fabric_backend is not None:
             state, prev_spikes, inflight = carry
-            drive, inflight, stats = self.fabric_backend.deliver_fabric(
+            with jax.named_scope("deliver"):
+                drive, inflight, stats = self.fabric_backend.deliver_fabric(
+                    prev_spikes,
+                    self.tables.src_tag,
+                    self.tables.src_dest,
+                    self.tables.cam_tag,
+                    self.tables.cam_syn,
+                    self.cluster_size,
+                    self.k_tags,
+                    inflight=inflight,
+                    external_activity=input_activity,
+                    queue_capacity=self.queue_capacity,
+                    syn_onehot=self.tables.cam_syn_onehot,
+                    entry_alive=self._fault_entry_alive,
+                )
+            state, spikes = neuron_mod.neuron_step(state, drive, self.params, i_ext)
+            # fabric mode always reports stats: drops/hops/latency/energy are
+            # the point of running the fabric model
+            return (state, spikes, inflight), (spikes, stats)
+        state, prev_spikes = carry
+        with jax.named_scope("deliver"):
+            drive, stats = backend_deliver(
+                self.backend,
                 prev_spikes,
                 self.tables.src_tag,
                 self.tables.src_dest,
@@ -391,33 +426,13 @@ class EventEngine:
                 self.tables.cam_syn,
                 self.cluster_size,
                 self.k_tags,
-                inflight=inflight,
                 external_activity=input_activity,
-                queue_capacity=self.queue_capacity,
+                # an autotuned "dense" winner bypasses compaction; the output
+                # contract still follows queue_capacity (stats read zero drops)
+                queue_capacity=None if self._autotune_dense else self.queue_capacity,
                 syn_onehot=self.tables.cam_syn_onehot,
-                entry_alive=self._fault_entry_alive,
+                with_stats=True,
             )
-            state, spikes = neuron_mod.neuron_step(state, drive, self.params, i_ext)
-            # fabric mode always reports stats: drops/hops/latency/energy are
-            # the point of running the fabric model
-            return (state, spikes, inflight), (spikes, stats)
-        state, prev_spikes = carry
-        drive, stats = backend_deliver(
-            self.backend,
-            prev_spikes,
-            self.tables.src_tag,
-            self.tables.src_dest,
-            self.tables.cam_tag,
-            self.tables.cam_syn,
-            self.cluster_size,
-            self.k_tags,
-            external_activity=input_activity,
-            # an autotuned "dense" winner bypasses compaction; the output
-            # contract still follows queue_capacity (stats read zero drops)
-            queue_capacity=None if self._autotune_dense else self.queue_capacity,
-            syn_onehot=self.tables.cam_syn_onehot,
-            with_stats=True,
-        )
         state, spikes = neuron_mod.neuron_step(state, drive, self.params, i_ext)
         out = spikes if self.queue_capacity is None else (spikes, stats)
         return (state, spikes), out
@@ -437,7 +452,9 @@ class EventEngine:
         """
         return self._jit_reset(carry, jnp.asarray(mask))
 
+    @jax.named_scope("reset_slots")
     def _reset_impl(self, carry, mask):
+        self.reset_traces += 1
         if mask.ndim < 1:
             raise ValueError("reset_slots needs a batched carry (mask per slot)")
         lead = tuple(carry[1].shape[: mask.ndim])
@@ -944,6 +961,7 @@ class ShardedEventEngine(EventEngine):
         qc = self.queue_capacity
 
         def _wrapped(carry, input_activity, i_ext=None):
+            self.step_traces += 1
             dtype = carry[1].dtype
             inp = jnp.asarray(input_activity, dtype)
             # shard_map in_specs cannot carry a None leaf: vacant external

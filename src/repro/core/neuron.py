@@ -75,6 +75,7 @@ def init_state(
     )
 
 
+@jax.named_scope("neuron_update")
 def neuron_step(
     state: NeuronState,
     drive: jax.Array,  # [..., N, 4] matched-event weight per synapse type (stage-2 output)

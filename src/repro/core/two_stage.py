@@ -91,6 +91,7 @@ jax.tree_util.register_dataclass(
 )
 
 
+@jax.named_scope("compact")
 def compact_events(spikes: jax.Array, capacity: int) -> EventQueue:
     """Compact active spikes into a fixed-capacity AER queue (jit-able).
 
@@ -246,6 +247,7 @@ def _scatter_count(
     return out.reshape(*batch_shape, size)
 
 
+@jax.named_scope("stage1")
 def stage1_route(
     spikes: jax.Array,  # [..., N] float event weights (0/1 spikes or rates)
     src_tag: jax.Array,  # [N, E] int32, -1 = empty
@@ -277,6 +279,7 @@ def stage1_route(
     return a.reshape(*batch_shape, n_clusters, k_tags)
 
 
+@jax.named_scope("stage1")
 def stage1_route_events(
     queue: EventQueue,  # src [..., Q], weight [..., Q]
     src_tag: jax.Array,  # [N, E]
@@ -343,6 +346,7 @@ jax.tree_util.register_dataclass(
 )
 
 
+@jax.named_scope("stage1")
 def stage1_route_events_fabric(
     queue: EventQueue,  # src [..., Q] LOCAL neuron ids into src_tag's rows
     src_tag: jax.Array,  # [N_local, E]
@@ -424,13 +428,14 @@ def stage1_route_events_fabric(
     if link_capacity is None:
         keep_cross = jnp.ones_like(cross)
     else:
-        bins = jnp.where(cross, src_tile * n_tiles + dst_tile, -1)
-        batch_shape = bins.shape[:-2]
-        flat_bins = bins.reshape(-1, bins.shape[-2] * bins.shape[-1])
-        _, keep_flat = jax.vmap(
-            lambda e: dispatch_slots(e, n_tiles * n_tiles, link_capacity)
-        )(flat_bins)
-        keep_cross = keep_flat.reshape(*batch_shape, *bins.shape[-2:])
+        with jax.named_scope("link_arbitration"):
+            bins = jnp.where(cross, src_tile * n_tiles + dst_tile, -1)
+            batch_shape = bins.shape[:-2]
+            flat_bins = bins.reshape(-1, bins.shape[-2] * bins.shape[-1])
+            _, keep_flat = jax.vmap(
+                lambda e: dispatch_slots(e, n_tiles * n_tiles, link_capacity)
+            )(flat_bins)
+            keep_cross = keep_flat.reshape(*batch_shape, *bins.shape[-2:])
 
     kept = valid & (~cross | keep_cross)
     if per_link_stats:
@@ -497,6 +502,7 @@ def precompute_syn_onehot(cam_syn: jax.Array, dtype=jnp.float32) -> jax.Array:
     return jax.nn.one_hot(cam_syn, N_SYN_TYPES, dtype=dtype)
 
 
+@jax.named_scope("stage2")
 def stage2_cam_match(
     activity: jax.Array,  # [..., n_clusters, K]
     cam_tag: jax.Array,  # [N, S] int32, -1 = empty

@@ -142,5 +142,6 @@ def cam_match_pallas(
             (b, n_clusters, cluster_size, N_SYN_TYPES), activity.dtype
         ),
         interpret=interpret,
+        name="cam_match",
     )(act3, tags3, syn3)
     return out.reshape(*batch_shape, n, N_SYN_TYPES)

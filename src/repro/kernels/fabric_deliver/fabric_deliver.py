@@ -197,6 +197,7 @@ def fabric_deliver_ring_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((1, k), dtype)],
         interpret=interpret,
+        name="fabric_deliver",
     )(cur2, ev_flat2, ev_w2, ext3, ring2, tags3, syn3)
     return (
         drive.reshape(*batch_shape, n, N_SYN_TYPES),

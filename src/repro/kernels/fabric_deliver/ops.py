@@ -325,15 +325,16 @@ def fabric_deliver_ring(
 
     # queue admission — compact_events truncation in mask form: the first
     # ``capacity`` active sources (ascending id = arbiter scan order) win
-    active = spikes != 0
-    cap = n if queue_capacity is None else min(int(queue_capacity), n)
-    if cap >= n:
-        in_q = active
-        dropped = jnp.zeros(batch_shape, jnp.int32)
-    else:
-        pos = jnp.cumsum(active, axis=-1, dtype=jnp.int32)
-        in_q = active & (pos <= cap)
-        dropped = jnp.maximum(pos[..., -1] - cap, 0)
+    with jax.named_scope("compact"):
+        active = spikes != 0
+        cap = n if queue_capacity is None else min(int(queue_capacity), n)
+        if cap >= n:
+            in_q = active
+            dropped = jnp.zeros(batch_shape, jnp.int32)
+        else:
+            pos = jnp.cumsum(active, axis=-1, dtype=jnp.int32)
+            in_q = active & (pos <= cap)
+            dropped = jnp.maximum(pos[..., -1] - cap, 0)
 
     act_all = jnp.take(in_q, entries.src, axis=-1) & entries.valid  # [..., M]
     # fault-severed entries (DESIGN.md §15) always drop — counted with the
@@ -349,14 +350,15 @@ def fabric_deliver_ring(
         kept = act_e
         drop_mask = fault_mask
     else:
-        cnt = (act_e & entries.cross).astype(jnp.int32)
-        excl = jnp.cumsum(cnt, axis=-1) - cnt
-        pos_in_link = excl - jnp.take(excl, entries.link_start, axis=-1)
-        keep_cross = pos_in_link < link_capacity
-        kept = act_e & (~entries.cross | keep_cross)
-        # disjoint masks (alive vs severed), so the union's per-bin counts
-        # sum to exactly the scalar fault + overflow totals
-        drop_mask = fault_mask | (act_e & entries.cross & ~keep_cross)
+        with jax.named_scope("link_arbitration"):
+            cnt = (act_e & entries.cross).astype(jnp.int32)
+            excl = jnp.cumsum(cnt, axis=-1) - cnt
+            pos_in_link = excl - jnp.take(excl, entries.link_start, axis=-1)
+            keep_cross = pos_in_link < link_capacity
+            kept = act_e & (~entries.cross | keep_cross)
+            # disjoint masks (alive vs severed), so the union's per-bin
+            # counts sum to exactly the scalar fault + overflow totals
+            drop_mask = fault_mask | (act_e & entries.cross & ~keep_cross)
 
     if per_link_stats:
         if n_tiles is None:

@@ -148,5 +148,6 @@ def fused_deliver_pallas(
         ),
         scratch_shapes=[pltpu.VMEM((1, k), dtype)],
         interpret=interpret,
+        name="fused_deliver",
     )(ev_flat2, ev_w2, ext3, tags3, syn3)
     return out.reshape(*batch_shape, n, N_SYN_TYPES)

@@ -22,6 +22,7 @@ from repro.kernels.fused_deliver.fused_deliver import fused_deliver_pallas
 from repro.kernels.fused_deliver.ref import fused_deliver_ref
 
 
+@jax.named_scope("stage1")
 def _event_entries_flat(
     queue: EventQueue, src_tag: jax.Array, src_dest: jax.Array, k_tags: int
 ) -> tuple[jax.Array, jax.Array]:

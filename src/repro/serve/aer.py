@@ -37,9 +37,11 @@ import dataclasses
 import hashlib
 import json
 from collections import deque
+from functools import partial
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation, annotate_function
 
 from repro.core.cnn import CompiledCnn, poker_neuron_params
 from repro.core.event_engine import (
@@ -63,7 +65,24 @@ __all__ = [
     "build_poker_engine",
     "session_from_meta",
     "table_v_models",
+    "POOL_COUNTERS",
 ]
+
+# Cumulative counters of a pool (AerSessionPool.counters()):
+#   steps, lane_steps, occupied_lane_steps  pool steps, slots stepped, and of
+#                                           those the slots holding a session;
+#   events_in                               sensor events read from sources;
+#   input_bytes, readback_bytes             host->device input, device->host
+#                                           spikes and drop counts;
+#   admitted, evicted                       fresh admissions, evictions;
+#   decided, forced, errored                evictions by outcome: threshold
+#                                           crossed, ended undecided, faulted;
+#   queue_dropped, link_dropped             AER-queue and fabric-link drops.
+POOL_COUNTERS = (
+    "steps", "lane_steps", "occupied_lane_steps", "events_in", "input_bytes",
+    "readback_bytes", "admitted", "evicted", "decided", "forced", "errored",
+    "queue_dropped", "link_dropped",
+)
 
 
 def session_from_meta(
@@ -328,6 +347,7 @@ class AerSessionPool:
         self.n_steps = 0  # engine steps taken (all slots advance together)
         self.quarantined: set[int] = set()  # slots withdrawn from admission
         self.last_stats = None  # DeliveryStats of the most recent step()
+        self.counts = dict.fromkeys(POOL_COUNTERS, 0)
         self._zero_act = np.zeros(
             (engine.n_clusters, engine.k_tags), dtype=np.float32
         )
@@ -553,7 +573,18 @@ class AerSessionPool:
             target = [new_pool.admit_restored(self.slots[i]) for i in occ]
             new_pool.carry = new_engine.splice_slots(new_pool.carry, target, sc)
         new_pool.n_steps = self.n_steps
+        new_pool.counts = dict(self.counts)
         return new_pool
+
+    def counters(self) -> dict[str, int]:
+        """Snapshot of the cumulative counters (:data:`POOL_COUNTERS`) and
+        the engine's ``step_traces``/``reset_traces``: the compilations of
+        its jitted step and slot reset."""
+        return {
+            **self.counts,
+            "step_traces": self.engine.step_traces,
+            "reset_traces": self.engine.reset_traces,
+        }
 
     # -- lifecycle ---------------------------------------------------------
     @property
@@ -609,6 +640,7 @@ class AerSessionPool:
         session.link_dropped = 0
         session.error = None  # a re-admitted session retries with a clean slate
         self.slots[slot] = session
+        self.counts["admitted"] += 1
         return slot
 
     def admit_restored(self, session: DvsSession) -> int:
@@ -642,6 +674,7 @@ class AerSessionPool:
         """
         return self.evict_many([slot])[0]
 
+    @partial(annotate_function, name="repro.pool.evict")
     def evict_many(self, slots: list[int]) -> list[SessionResult]:
         """Evict several tenants with ONE masked carry reset.
 
@@ -676,8 +709,14 @@ class AerSessionPool:
                     error=sess.error,
                 )
             )
+            outcome = (
+                "errored" if sess.error is not None
+                else "decided" if decided else "forced"
+            )
+            self.counts[outcome] += 1
             self.slots[slot] = None
             mask[slot] = True
+        self.counts["evicted"] += len(slots)
         if mask.any():
             self.carry = self.engine.reset_slots(self.carry, mask)
         return results
@@ -742,7 +781,8 @@ class AerSessionPool:
         collecting any, so the shards' device work overlaps
         (serve/sharded.py, DESIGN.md §17).
         """
-        return self.finish_step(self.begin_step())
+        with StepTraceAnnotation("repro.pool.step", step_num=self.n_steps):
+            return self.finish_step(self.begin_step())
 
     def begin_step(self):
         """Gather this step's inputs and dispatch the engine step.
@@ -751,9 +791,13 @@ class AerSessionPool:
         asynchronous, so this returns as soon as the step is enqueued on the
         device — nothing here blocks on the result.
         """
-        self.carry, out = self.engine.step(self.carry, self.gather_inputs())
+        inputs = self.gather_inputs()
+        with TraceAnnotation("repro.pool.dispatch"):
+            self.carry, out = self.engine.step(self.carry, inputs)
+        self.counts["input_bytes"] += inputs.nbytes
         return out
 
+    @partial(annotate_function, name="repro.pool.gather")
     def gather_inputs(self) -> np.ndarray:
         """This step's external tag activity ``[P, nc_total, K_max]`` (host).
 
@@ -762,15 +806,16 @@ class AerSessionPool:
         """
         multi = len(self.models) > 1
         acts = []
+        n_events = 0
         for sess in self.slots:
             if sess is None:
                 acts.append(self._zero_act)
                 continue
             cc_m = self.models[sess.model]
+            events = sess.source.events(sess.step)
+            n_events += len(events)
             try:
-                a = cc_m.input_activity(
-                    sess.source.events(sess.step), on_invalid=self.cfg.on_invalid
-                )
+                a = cc_m.input_activity(events, on_invalid=self.cfg.on_invalid)
             except ValueError as e:
                 sess.error = str(e)
                 a = None
@@ -788,23 +833,42 @@ class AerSessionPool:
                     slab.cluster_lo : slab.cluster_hi, : slab.k_tags
                 ] = a * self.cfg.drive
                 acts.append(full)
+        self.counts["events_in"] += n_events
         return np.stack(acts)
 
     def finish_step(self, out) -> np.ndarray:
         """Block on a dispatched step's results and apply them per session."""
-        spikes, stats = out if isinstance(out, tuple) else (out, None)
-        spikes = np.asarray(spikes)
+        with TraceAnnotation("repro.pool.readback"):
+            spikes, stats = out if isinstance(out, tuple) else (out, None)
+            spikes = np.asarray(spikes)
+            dropped = None if stats is None else np.asarray(stats.dropped)
+            link_dropped = (
+                None
+                if stats is None or stats.link_dropped is None
+                else np.asarray(stats.link_dropped)
+            )
+        return self._readout(spikes, stats, dropped, link_dropped)
+
+    @partial(annotate_function, name="repro.pool.readout")
+    def _readout(self, spikes, stats, dropped, link_dropped) -> np.ndarray:
+        """Count the step and add its output spikes and drops to each
+        session's accumulators."""
         self.last_stats = stats  # watchdog raw material (serve/health.py)
         self.n_steps += 1
+        counts = self.counts
+        counts["steps"] += 1
+        counts["lane_steps"] += self.cfg.pool_size
+        counts["occupied_lane_steps"] += self.cfg.pool_size - self.slots.count(None)
+        counts["readback_bytes"] += spikes.nbytes
+        if dropped is not None:
+            counts["readback_bytes"] += dropped.nbytes
+            counts["queue_dropped"] += int(dropped.sum())
+        if link_dropped is not None:
+            counts["readback_bytes"] += link_dropped.nbytes
+            counts["link_dropped"] += int(link_dropped.sum())
 
         if self.profile is not None and stats is not None:
             self.profile.observe(stats)
-        dropped = None if stats is None else np.asarray(stats.dropped)
-        link_dropped = (
-            None
-            if stats is None or stats.link_dropped is None
-            else np.asarray(stats.link_dropped)
-        )
         if link_dropped is not None and link_dropped.ndim > 1:
             # per_link_stats mode: collapse the [P, T*T] attribution axis for
             # the per-session counters (the profile keeps the full matrix)
@@ -839,6 +903,7 @@ class AerSessionPool:
         finished = decided or sess.step >= self.cfg.max_steps or sess.error is not None
         return decided, finished
 
+    @partial(annotate_function, name="repro.pool.decide")
     def finished_slots(self) -> list[int]:
         """Slots whose tenant has reached a decision (or the step cap)."""
         return [
@@ -993,6 +1058,7 @@ class AerSessionPool:
         return pool
 
     # -- drain loop --------------------------------------------------------
+    @partial(annotate_function, name="repro.pool.admit")
     def admit_next(self, pending: deque) -> DvsSession | None:
         """Admit the first admissible session from the ``pending`` queue.
 

@@ -48,9 +48,11 @@ from __future__ import annotations
 import dataclasses
 import json
 from collections import deque
+from functools import partial
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, annotate_function
 
 from repro.core.cnn import CompiledCnn, poker_neuron_params
 from repro.core.compiler import device_slab_placement, session_rate
@@ -58,6 +60,7 @@ from repro.core.dispatch import DeliveryStats
 from repro.core.event_engine import ModelRegistry, ShardedEventEngine
 from repro.core.tags import RoutingTables
 from repro.serve.aer import (
+    POOL_COUNTERS,
     AerServeConfig,
     AerSessionPool,
     CheckpointMismatchError,
@@ -262,6 +265,11 @@ class ShardedSessionPool:
         ]
         self.dead: set[int] = set()  # killed shards keep their index
         self.n_steps = 0
+        # fleet counters: submissions queued and refused (AdmissionError);
+        # killed shards' pool counters are kept so the sums stay cumulative
+        self.submitted = 0
+        self.refused = 0
+        self._killed_counts = dict.fromkeys(POOL_COUNTERS, 0)
         # admission scoring: predicted per-session fabric traffic by model
         # (the compiler's traffic model — DESIGN.md §13 driving §17)
         self._rates = {
@@ -296,6 +304,18 @@ class ShardedSessionPool:
         return any(
             self.queues[i] or self.pools[i].occupied for i in self.live_shards()
         )
+
+    def counters(self) -> dict[str, int]:
+        """The shards' pool counters summed (``AerSessionPool.counters``,
+        killed shards' up to their loss), with ``submitted`` and
+        ``refused``."""
+        total = dict(self._killed_counts, step_traces=0, reset_traces=0)
+        for i in self.live_shards():
+            for k, v in self.pools[i].counters().items():
+                total[k] += v
+        total["submitted"] = self.submitted
+        total["refused"] = self.refused
+        return total
 
     def occupancy(self) -> dict[int, tuple[int, int]]:
         """Per live shard: (occupied slots, queued sessions)."""
@@ -413,6 +433,7 @@ class ShardedSessionPool:
         )
 
     # -- admission (DESIGN.md §17 layer 2) ---------------------------------
+    @partial(annotate_function, name="repro.fleet.submit")
     def submit(self, session: DvsSession) -> int:
         """Route ``session`` to the least-loaded admissible shard.
 
@@ -427,6 +448,7 @@ class ShardedSessionPool:
         del rate
         live = self.live_shards()
         if not live:
+            self.refused += 1
             raise AdmissionError("no live shards remain in the fleet")
         # a queued session bound for a free slot does not consume queue
         # room: queue_depth bounds only the overflow beyond free slots
@@ -442,12 +464,14 @@ class ShardedSessionPool:
             < len(self.pools[i].free_slots) + self.shards.queue_depth
         ]
         if not cands:
+            self.refused += 1
             raise AdmissionError(
                 f"fleet at capacity: every live shard's waiting queue is at "
                 f"queue_depth={self.shards.queue_depth}"
             )
         best = min(cands, key=lambda i: (self._score(i), i))
         self.queues[best].append(session)
+        self.submitted += 1
         return best
 
     def _backfill(self) -> None:
@@ -464,13 +488,14 @@ class ShardedSessionPool:
         a fleet step costs max(shard step), not sum (the multi-host analogy
         at single-process scale).
         """
-        self._backfill()
-        live = self.live_shards()
-        outs = [self.pools[i].begin_step() for i in live]
-        for i, out in zip(live, outs):
-            self.pools[i].finish_step(out)
-        self._observe_rates(live)
-        self.n_steps += 1
+        with StepTraceAnnotation("repro.fleet.step", step_num=self.n_steps):
+            self._backfill()
+            live = self.live_shards()
+            outs = [self.pools[i].begin_step() for i in live]
+            for i, out in zip(live, outs):
+                self.pools[i].finish_step(out)
+            self._observe_rates(live)
+            self.n_steps += 1
 
     def evict_finished(self) -> list[SessionResult]:
         results: list[SessionResult] = []
@@ -571,6 +596,8 @@ class ShardedSessionPool:
         if shard_id in self.dead:
             raise ValueError(f"shard {shard_id} is already dead")
         self.dead.add(shard_id)
+        for k in POOL_COUNTERS:
+            self._killed_counts[k] += self.pools[shard_id].counts[k]
         self.pools[shard_id] = None
         self.queues[shard_id] = deque()
 

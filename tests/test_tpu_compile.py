@@ -12,6 +12,8 @@ collects would break the other workers. The tests skip where no v5e
 topology can be described.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -100,6 +102,19 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, nc):
     text, compiled = _compile(fn, *shapes)
     assert "tpu_custom_call" in text
     assert compiled.memory_analysis() is not None
+
+
+@pytest.mark.parametrize("kernel", ["cam_match", "fused_deliver", "fabric_deliver"])
+def test_compiled_kernel_is_named_for_the_kernel(one_chip, kernel):
+    """The kernel's instruction in the compiled program, which a profiler
+    trace names its op by, carries the ``pallas_call``'s own name: a change
+    to the wrapper around it leaves the name the trace readers match."""
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, *shapes = _kernel_call(kernel, 6, sds)
+    _, compiled = _compile(fn, *shapes)
+    assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*custom-call\(", compiled.as_text())
 
 
 @pytest.mark.parametrize("kernel", ["cam_match", "fused_deliver", "fabric_deliver"])
