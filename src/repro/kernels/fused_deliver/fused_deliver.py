@@ -10,8 +10,12 @@ feeds the core's broadcast driver directly (no DRAM between fabric and CAM).
 
 Inputs are the AER queue's SRAM entries, pre-gathered and flattened to
 ``ev_flat[B, QE]`` (``dest * K + tag`` per queued (event, SRAM-entry) pair,
-``-1`` = empty) with matching weights ``ev_w[B, QE]`` — event count, not
-network size, so QE = Q*E stays small at real sparsity levels.
+``-1`` = empty) with matching weights ``ev_w[B, QE]``. The lossless queue
+has a slot per neuron (QE = N*E), but its occupied entries form a prefix:
+a scalar-prefetched ``n_entries[B]`` gives each batch row's occupied count,
+and stage 1 scans only ``cdiv(n_entries[b], ev_chunk)`` chunks of it, so its
+work follows the event count, not the network size. The chunks past the
+prefix hold only empty entries (weight 0) and would add exactly 0.
 
 Grid ``(B, n_clusters, neuron-tile)``; TPU grids execute sequentially with
 the last dimension minor, so the row scratch built at tile ``j == 0`` of a
@@ -42,6 +46,7 @@ from repro.kernels.cam_match.cam_match import (
 
 
 def _fused_deliver_kernel(
+    n_entries_ref,  # SMEM [B] int32 — occupied prefix of each row's entries
     ev_flat_ref,  # [1, 1, QE] int32 — flat (dest*K + tag) per queued entry, -1 empty
     ev_w_ref,  # [1, 1, QE] — event weight per entry (0 for empty)
     ext_ref,  # [1, 1, K] — external input activity for this (batch, cluster)
@@ -53,6 +58,7 @@ def _fused_deliver_kernel(
     k_tags: int,
     ev_chunk: int,
 ):
+    bi = pl.program_id(0)
     c = pl.program_id(1)
     j = pl.program_id(2)
 
@@ -60,7 +66,8 @@ def _fused_deliver_kernel(
     def _build_activity_row():
         # stage 1 for (b, c): accumulate this cluster's K-row from the queue.
         base = c * k_tags
-        qe = ev_flat_ref.shape[2]
+        full = ev_flat_ref.shape[2] // ev_chunk
+        n_chunks = jnp.minimum((n_entries_ref[bi] + ev_chunk - 1) // ev_chunk, full)
 
         def chunk_body(i, acc):
             at = pl.ds(pl.multiple_of(i * ev_chunk, ev_chunk), ev_chunk)
@@ -76,7 +83,7 @@ def _fused_deliver_kernel(
             )
 
         row = jax.lax.fori_loop(
-            0, qe // ev_chunk, chunk_body, ext_ref[0].astype(jnp.float32)
+            0, n_chunks, chunk_body, ext_ref[0].astype(jnp.float32)
         )
         act_ref[...] = row.astype(act_ref.dtype)
 
@@ -96,6 +103,7 @@ def fused_deliver_pallas(
     external_activity: jax.Array,  # [..., n_clusters, K]
     cluster_size: int,
     k_tags: int,
+    n_entries: jax.Array | None = None,  # [...] int32 occupied prefix; None = all
     block_c: int = 16,
     interpret: bool = True,
 ) -> jax.Array:  # [..., N, N_SYN_TYPES]
@@ -122,6 +130,9 @@ def fused_deliver_pallas(
         pad = ((0, 0), (0, 0), (0, qe_pad - qe))
         ev_flat2 = jnp.pad(ev_flat2, pad, constant_values=-1)
         ev_w2 = jnp.pad(ev_w2, pad)
+    if n_entries is None:
+        n_entries = jnp.full((b,), qe, jnp.int32)
+    n_entries = jnp.asarray(n_entries, jnp.int32).reshape(b)
 
     ext3 = jnp.broadcast_to(
         external_activity, (*batch_shape, n_clusters, k)
@@ -132,22 +143,25 @@ def fused_deliver_pallas(
 
     out = pl.pallas_call(
         functools.partial(_fused_deliver_kernel, k_tags=k, ev_chunk=ev_chunk),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, qe_pad), lambda bi, i, j: (bi, 0, 0)),
-            pl.BlockSpec((1, 1, qe_pad), lambda bi, i, j: (bi, 0, 0)),
-            pl.BlockSpec((1, 1, k), lambda bi, i, j: (bi, 0, i)),
-            pl.BlockSpec((1, block_c, s), lambda bi, i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_c, s), lambda bi, i, j: (i, j, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, block_c, N_SYN_TYPES), lambda bi, i, j: (bi, i, j, 0)
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, qe_pad), lambda bi, i, j, ne: (bi, 0, 0)),
+                pl.BlockSpec((1, 1, qe_pad), lambda bi, i, j, ne: (bi, 0, 0)),
+                pl.BlockSpec((1, 1, k), lambda bi, i, j, ne: (bi, 0, i)),
+                pl.BlockSpec((1, block_c, s), lambda bi, i, j, ne: (i, j, 0)),
+                pl.BlockSpec((1, block_c, s), lambda bi, i, j, ne: (i, j, 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, block_c, N_SYN_TYPES), lambda bi, i, j, ne: (bi, i, j, 0)
+            ),
+            scratch_shapes=[pltpu.VMEM((1, k), dtype)],
         ),
         out_shape=jax.ShapeDtypeStruct(
             (b, n_clusters, cluster_size, N_SYN_TYPES), dtype
         ),
-        scratch_shapes=[pltpu.VMEM((1, k), dtype)],
         interpret=interpret,
         name="fused_deliver",
-    )(ev_flat2, ev_w2, ext3, tags3, syn3)
+    )(n_entries, ev_flat2, ev_w2, ext3, tags3, syn3)
     return out.reshape(*batch_shape, n, N_SYN_TYPES)

@@ -25,14 +25,21 @@ from repro.kernels.fused_deliver.ref import fused_deliver_ref
 @jax.named_scope("stage1")
 def _event_entries_flat(
     queue: EventQueue, src_tag: jax.Array, src_dest: jax.Array, k_tags: int
-) -> tuple[jax.Array, jax.Array]:
-    """Queue -> kernel inputs: flat ``dest*K + tag`` [..., Q*E] + weights."""
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Queue -> kernel inputs: flat ``dest*K + tag`` [..., Q*E], weights,
+    and the occupied entry count [...] — the queue holds its events in a
+    prefix of its slots, so every valid entry lies in ``[0, n_entries)``."""
     ev_tag, ev_dest = gather_event_entries(queue, src_tag, src_dest)
     valid = ev_tag >= 0
     ev_flat = jnp.where(valid, ev_dest * k_tags + ev_tag, -1)
     ev_w = queue.weight[..., None] * valid.astype(queue.weight.dtype)
     batch_shape = queue.src.shape[:-1]
-    return ev_flat.reshape(*batch_shape, -1), ev_w.reshape(*batch_shape, -1)
+    n_entries = (queue.src >= 0).sum(-1, dtype=jnp.int32) * src_tag.shape[1]
+    return (
+        ev_flat.reshape(*batch_shape, -1),
+        ev_w.reshape(*batch_shape, -1),
+        n_entries,
+    )
 
 
 def fused_deliver(
@@ -58,7 +65,7 @@ def fused_deliver(
                 external_activity=external_activity, syn_onehot=syn_onehot,
             )
         interpret = False
-    ev_flat, ev_w = _event_entries_flat(queue, src_tag, src_dest, k_tags)
+    ev_flat, ev_w, n_entries = _event_entries_flat(queue, src_tag, src_dest, k_tags)
     n_clusters = src_tag.shape[0] // cluster_size
     if external_activity is None:
         external_activity = jnp.zeros(
@@ -66,5 +73,5 @@ def fused_deliver(
         )
     return fused_deliver_pallas(
         ev_flat, ev_w, cam_tag, cam_syn, external_activity, cluster_size, k_tags,
-        block_c=block_c, interpret=interpret,
+        n_entries=n_entries, block_c=block_c, interpret=interpret,
     )

@@ -77,11 +77,14 @@ __all__ = [
 #   admitted, evicted                       fresh admissions, evictions;
 #   decided, forced, errored                evictions by outcome: threshold
 #                                           crossed, ended undecided, faulted;
-#   queue_dropped, link_dropped             AER-queue and fabric-link drops.
+#   queue_dropped, link_dropped             AER-queue and fabric-link drops;
+#   queued_sources                          spiking neurons read back: the
+#                                           sources the next step's AER
+#                                           queue holds.
 POOL_COUNTERS = (
     "steps", "lane_steps", "occupied_lane_steps", "events_in", "input_bytes",
     "readback_bytes", "admitted", "evicted", "decided", "forced", "errored",
-    "queue_dropped", "link_dropped",
+    "queue_dropped", "link_dropped", "queued_sources",
 )
 
 
@@ -860,6 +863,7 @@ class AerSessionPool:
         counts["lane_steps"] += self.cfg.pool_size
         counts["occupied_lane_steps"] += self.cfg.pool_size - self.slots.count(None)
         counts["readback_bytes"] += spikes.nbytes
+        counts["queued_sources"] += int(np.count_nonzero(spikes))
         if dropped is not None:
             counts["readback_bytes"] += dropped.nbytes
             counts["queue_dropped"] += int(dropped.sum())

@@ -231,6 +231,65 @@ def test_fused_deliver_pallas_matches_ref(b):
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("active,capacity", [
+    ((0, 0), 48),  # no events: the external-input row alone
+    ((3, 0, 5), 48),  # 48 and 80 entries: one partial chunk
+    ((8, 16), 48),  # 128 and 256 entries: exact chunk boundaries
+    ((48, 11), 48),  # every source active: all 6 chunks
+    ((48, 44), 40),  # over capacity: 40 kept, the rest dropped
+], ids=["empty", "partial", "boundary", "all", "drops"])
+def test_fused_deliver_scans_only_occupied_entries(monkeypatch, active, capacity):
+    """Stage 1 stops after the chunk that holds the queue's last occupied
+    entry: bit-identical to the full scan of the entry axis, equal to the
+    jnp reference, and blind to whatever lies in the chunks past it."""
+    import repro.kernels.cam_match.cam_match as cm
+    from repro.core.two_stage import compact_events
+    from repro.kernels.fused_deliver import fused_deliver, fused_deliver_pallas
+    from repro.kernels.fused_deliver import fused_deliver_ref
+    from repro.kernels.fused_deliver.ops import _event_entries_flat
+
+    ncl, c, s, k, e, chunk = 3, 16, 8, 32, 16, 128
+    monkeypatch.setattr(cm, "_PLANE_BUDGET_ELEMS", chunk * k)
+    n, b = ncl * c, len(active)
+    rng = np.random.default_rng(sum(active) + capacity)
+    src_tag = jnp.asarray(rng.integers(-1, k, (n, e)), jnp.int32)
+    src_dest = jnp.asarray(rng.integers(0, ncl, (n, e)), jnp.int32)
+    cam_tag = jnp.asarray(rng.integers(-1, k, (n, s)), jnp.int32)
+    cam_syn = jnp.asarray(rng.integers(0, 4, (n, s)), jnp.int32)
+    spikes = np.zeros((b, n), np.float32)
+    for i, a in enumerate(active):
+        spikes[i, rng.choice(n, a, replace=False)] = rng.integers(1, 4, a)
+    ext = jnp.asarray(rng.random((b, ncl, k)), jnp.float32)
+    queue = compact_events(jnp.asarray(spikes), capacity)
+    assert np.asarray(queue.dropped).tolist() == [max(0, a - capacity) for a in active]
+
+    ev_flat, ev_w, n_entries = _event_entries_flat(queue, src_tag, src_dest, k)
+    assert ev_flat.shape[-1] // chunk >= 4
+    assert np.asarray(n_entries).tolist() == [min(a, capacity) * e for a in active]
+
+    def kernel(f, w, ne):
+        return fused_deliver_pallas(f, w, cam_tag, cam_syn, ext, c, k, n_entries=ne,
+                                    block_c=8, interpret=True)
+
+    bounded = kernel(ev_flat, ev_w, n_entries)
+    np.testing.assert_array_equal(np.asarray(bounded), np.asarray(kernel(ev_flat, ev_w, None)))
+    out_ops = fused_deliver(queue, src_tag, src_dest, cam_tag, cam_syn, c, k,
+                            external_activity=ext, block_c=8, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out_ops), np.asarray(bounded))
+    out_r = fused_deliver_ref(queue, src_tag, src_dest, cam_tag, cam_syn, c, k,
+                              external_activity=ext)
+    np.testing.assert_allclose(np.asarray(bounded), np.asarray(out_r), rtol=1e-5, atol=1e-5)
+
+    # live entries planted past each row's last scanned chunk are never read
+    scanned = -(-np.asarray(n_entries) // chunk) * chunk
+    tail = np.arange(ev_flat.shape[-1])[None, :] >= scanned[:, None]
+    poisoned = kernel(jnp.where(tail, 0, ev_flat), jnp.where(tail, 1.0, ev_w), n_entries)
+    np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(bounded))
+    if tail.any():
+        full = kernel(jnp.where(tail, 0, ev_flat), jnp.where(tail, 1.0, ev_w), None)
+        assert not np.array_equal(np.asarray(full), np.asarray(bounded))
+
+
 # ---------------------------------------------------------------------------
 # engine: batched carry == independent single runs
 # ---------------------------------------------------------------------------

@@ -118,6 +118,26 @@ def test_fleet_counters_sum_shards_and_count_refusals(cc):
     assert c["evicted"] == len(results)
 
 
+def test_queued_sources_counts_the_spikes_read_back(cc):
+    """``queued_sources`` adds up the non-zero spikes every step reads back
+    (the sources the next step's queue holds), and a fleet sums it."""
+    pool = _pool(cc)
+    for i in range(4):
+        pool.admit(_session(i, events_per_step=64))
+    spiked = sum(int(np.count_nonzero(pool.step())) for _ in range(6))
+    assert pool.counters()["queued_sources"] == spiked > 0
+
+    fleet = ShardedSessionPool(cc, AerServeConfig(pool_size=2, max_steps=25),
+                               ShardConfig(n_shards=2, queue_depth=2))
+    for i in range(4):
+        fleet.submit(_session(i, events_per_step=64))
+    for _ in range(6):
+        fleet.step()
+    shards = [p.counters()["queued_sources"] for p in fleet.pools]
+    assert fleet.counters()["queued_sources"] == sum(shards)
+    assert all(q > 0 for q in shards)
+
+
 def _host_events(log_dir: str) -> list:
     from jax.profiler import ProfileData
 

@@ -74,12 +74,13 @@ def _kernel_call(kernel: str, nc: int, sds):
         )
     if kernel == "fused_deliver":
         qe = n * E  # lossless AER queue: every neuron, every SRAM entry
+        # n_entries is a runtime operand: stage 1's trip count is dynamic
         return (
-            lambda f, w, t, s, x: fused_deliver_pallas(
-                f, w, t, s, x, C, K, interpret=False
+            lambda f, w, t, s, x, ne: fused_deliver_pallas(
+                f, w, t, s, x, C, K, n_entries=ne, interpret=False
             ),
             sds((POOL, qe), jnp.int32), sds((POOL, qe)), tags, tags,
-            sds((POOL, nc, K)),
+            sds((POOL, nc, K)), sds((POOL,), jnp.int32),
         )
     m = n  # occupied SRAM entries: about one per neuron in the Table-V CNN
     return (
