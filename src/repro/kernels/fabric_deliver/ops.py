@@ -283,6 +283,20 @@ def _ring_update_jnp(
     return drive, ring
 
 
+def _prefix_sum(x: jax.Array) -> jax.Array:
+    """Inclusive prefix sum over the last axis, as the windowed sum that
+    ``jnp.cumsum`` lowers to. Written out, its instructions keep the
+    caller's ``named_scope`` (``cumsum`` lowers out of line, and its
+    instructions lose the scope)."""
+    n = x.shape[-1]
+    return jax.lax.reduce_window(
+        x, jnp.zeros((), x.dtype), jax.lax.add,
+        window_dimensions=(1,) * (x.ndim - 1) + (n,),
+        window_strides=(1,) * x.ndim,
+        padding=((0, 0),) * (x.ndim - 1) + ((n - 1, 0),),
+    )
+
+
 def fabric_deliver_ring(
     spikes: jax.Array,  # [..., N]
     entries: FabricEntries,
@@ -332,7 +346,7 @@ def fabric_deliver_ring(
             in_q = active
             dropped = jnp.zeros(batch_shape, jnp.int32)
         else:
-            pos = jnp.cumsum(active, axis=-1, dtype=jnp.int32)
+            pos = _prefix_sum(active.astype(jnp.int32))
             in_q = active & (pos <= cap)
             dropped = jnp.maximum(pos[..., -1] - cap, 0)
 
@@ -352,7 +366,7 @@ def fabric_deliver_ring(
     else:
         with jax.named_scope("link_arbitration"):
             cnt = (act_e & entries.cross).astype(jnp.int32)
-            excl = jnp.cumsum(cnt, axis=-1) - cnt
+            excl = _prefix_sum(cnt) - cnt
             pos_in_link = excl - jnp.take(excl, entries.link_start, axis=-1)
             keep_cross = pos_in_link < link_capacity
             kept = act_e & (~entries.cross | keep_cross)

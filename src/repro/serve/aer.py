@@ -73,18 +73,23 @@ __all__ = [
 #                                           those the slots holding a session;
 #   events_in                               sensor events read from sources;
 #   input_bytes, readback_bytes             host->device input, device->host
-#                                           spikes and drop counts;
+#                                           spikes, drop and mesh counts;
 #   admitted, evicted                       fresh admissions, evictions;
 #   decided, forced, errored                evictions by outcome: threshold
 #                                           crossed, ended undecided, faulted;
 #   queue_dropped, link_dropped             AER-queue and fabric-link drops;
 #   queued_sources                          spiking neurons read back: the
 #                                           sources the next step's AER
-#                                           queue holds.
+#                                           queue holds;
+#   delivered, mesh_hops                    fabric mode: SRAM entries
+#                                           delivered, and the chip
+#                                           crossings of those entries
+#                                           (DeliveryStats.delivered,
+#                                           .hops); 0 on other backends.
 POOL_COUNTERS = (
     "steps", "lane_steps", "occupied_lane_steps", "events_in", "input_bytes",
     "readback_bytes", "admitted", "evicted", "decided", "forced", "errored",
-    "queue_dropped", "link_dropped", "queued_sources",
+    "queue_dropped", "link_dropped", "queued_sources", "delivered", "mesh_hops",
 )
 
 
@@ -850,10 +855,15 @@ class AerSessionPool:
                 if stats is None or stats.link_dropped is None
                 else np.asarray(stats.link_dropped)
             )
-        return self._readout(spikes, stats, dropped, link_dropped)
+            mesh = (
+                None
+                if stats is None or stats.delivered is None
+                else (np.asarray(stats.delivered), np.asarray(stats.hops))
+            )
+        return self._readout(spikes, stats, dropped, link_dropped, mesh)
 
     @partial(annotate_function, name="repro.pool.readout")
-    def _readout(self, spikes, stats, dropped, link_dropped) -> np.ndarray:
+    def _readout(self, spikes, stats, dropped, link_dropped, mesh) -> np.ndarray:
         """Count the step and add its output spikes and drops to each
         session's accumulators."""
         self.last_stats = stats  # watchdog raw material (serve/health.py)
@@ -870,6 +880,11 @@ class AerSessionPool:
         if link_dropped is not None:
             counts["readback_bytes"] += link_dropped.nbytes
             counts["link_dropped"] += int(link_dropped.sum())
+        if mesh is not None:
+            delivered, hops = mesh
+            counts["readback_bytes"] += delivered.nbytes + hops.nbytes
+            counts["delivered"] += int(delivered.sum())
+            counts["mesh_hops"] += int(hops.sum())
 
         if self.profile is not None and stats is not None:
             self.profile.observe(stats)

@@ -138,6 +138,51 @@ def test_queued_sources_counts_the_spikes_read_back(cc):
     assert all(q > 0 for q in shards)
 
 
+def _step_mesh_stats(pool) -> tuple[int, int]:
+    """One step's delivered SRAM entries and their chip crossings."""
+    pool.step()
+    stats = pool.last_stats
+    return int(np.asarray(stats.delivered).sum()), int(np.asarray(stats.hops).sum())
+
+
+def test_mesh_counters_sum_the_steps_delivery_stats(cc):
+    """A fabric pool counts the SRAM entries it delivered and their chip
+    crossings, as each step's ``DeliveryStats`` give them; under the 3x3
+    board's placement (cores 0-3 on one chip, 4-5 on the next) the conv
+    layer's events cross. A fused pool reads back no mesh counts."""
+    pool = _pool(cc, backend="fabric")
+    assert list(pool.engine.fabric_model.tile_of_cluster) == [0, 0, 0, 0, 1, 1]
+    for i in range(4):
+        pool.admit(_session(i, events_per_step=64))
+    steps = [_step_mesh_stats(pool) for _ in range(6)]
+    c = pool.counters()
+    assert c["delivered"] == sum(d for d, _ in steps) > 0
+    assert c["mesh_hops"] == sum(h for _, h in steps) > 0
+
+    fused = _pool(cc, backend="fused")
+    for i in range(4):
+        fused.admit(_session(i, events_per_step=64))
+    for _ in range(6):
+        fused.step()
+    c = fused.counters()
+    assert c["queued_sources"] > 0
+    assert c["delivered"] == c["mesh_hops"] == 0
+
+
+def test_fleet_sums_the_mesh_counters_over_its_shards(cc):
+    fleet = ShardedSessionPool(cc, AerServeConfig(pool_size=2, max_steps=25),
+                               ShardConfig(n_shards=2, queue_depth=2, backend="fabric"))
+    for i in range(4):
+        fleet.submit(_session(i, events_per_step=64))
+    for _ in range(6):
+        fleet.step()
+    shards = [p.counters() for p in fleet.pools]
+    c = fleet.counters()
+    for k in ("delivered", "mesh_hops"):
+        assert c[k] == sum(s[k] for s in shards), k
+        assert all(s[k] > 0 for s in shards), k
+
+
 def _host_events(log_dir: str) -> list:
     from jax.profiler import ProfileData
 

@@ -3,8 +3,10 @@
 The three delivery kernels and the jitted serving-pool step are compiled by
 the TPU compiler for a described v5e device at the served Table-V shapes
 (pool of 32 slots; 6 clusters for one resident model, 12 for two; 256
-neurons per cluster, K = 1024 tags, 64 CAM words). Interpret mode accepts
-block layouts and VMEM footprints the chip refuses; these compiles do not.
+neurons per cluster, K = 1024 tags, 64 CAM words), and the fabric kernel
+and pool step at the benchmark's fabric cell (128 slots, one resident).
+Interpret mode accepts block layouts and VMEM footprints the chip refuses;
+these compiles do not.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, so a description made while pytest
@@ -25,6 +27,9 @@ from repro.kernels.fused_deliver.fused_deliver import fused_deliver_pallas
 
 POOL, C, K, S, E = 32, 256, 1024, 64, 16
 MAX_DELAY = 1  # the served fabric's delay horizon
+CELL_POOL = 128  # slots of the benchmark's tablev-3x3-fabric cell
+CELL_ENTRIES = 1280  # occupied SRAM entries of one Table-V CNN
+STEP_SCOPES = {"compact", "link_arbitration", "deliver", "neuron_update", "reset_slots"}
 
 
 @pytest.fixture(scope="module")
@@ -64,7 +69,7 @@ def _compile(fn, *shapes):
     return text, compiled
 
 
-def _kernel_call(kernel: str, nc: int, sds):
+def _kernel_call(kernel: str, nc: int, sds, pool: int = POOL, m: int | None = None):
     n = nc * C
     tags = sds((n, S), jnp.int32)
     if kernel == "cam_match":
@@ -82,14 +87,14 @@ def _kernel_call(kernel: str, nc: int, sds):
             sds((POOL, qe), jnp.int32), sds((POOL, qe)), tags, tags,
             sds((POOL, nc, K)), sds((POOL,), jnp.int32),
         )
-    m = n  # occupied SRAM entries: about one per neuron in the Table-V CNN
+    m = n if m is None else m  # occupied SRAM entries: about one per neuron
     return (
         lambda f, w, r, c, x, t, s: fabric_deliver_ring_pallas(
             f, w, r, c, x, t, s, C, K, MAX_DELAY, interpret=False
         ),
-        sds((m,), jnp.int32), sds((POOL, m)),
-        sds((POOL, MAX_DELAY + 1, nc, K)), sds((), jnp.int32),
-        sds((POOL, nc, K)), tags, tags,
+        sds((m,), jnp.int32), sds((pool, m)),
+        sds((pool, MAX_DELAY + 1, nc, K)), sds((), jnp.int32),
+        sds((pool, nc, K)), tags, tags,
     )
 
 
@@ -100,6 +105,19 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, nc):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     fn, *shapes = _kernel_call(kernel, nc, sds)
+    text, compiled = _compile(fn, *shapes)
+    assert "tpu_custom_call" in text
+    assert compiled.memory_analysis() is not None
+
+
+def test_fabric_kernel_compiles_for_v5e_at_the_cell_shapes(one_chip):
+    """``fabric_deliver`` as the fabric cell runs it: 128 slots, one resident
+    (nc 6), a ring of D + 1 = 2 slots and the network's 1,280 occupied SRAM
+    entries, which the kernel pads to whole event chunks."""
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, *shapes = _kernel_call("fabric_deliver", 6, sds, pool=CELL_POOL, m=CELL_ENTRIES)
     text, compiled = _compile(fn, *shapes)
     assert "tpu_custom_call" in text
     assert compiled.memory_analysis() is not None
@@ -173,3 +191,39 @@ def test_two_model_pool_step_compiles_for_v5e(one_chip, backend):
     text, compiled = _compile(eng.step, carry, inp)
     assert "tpu_custom_call" in text
     assert compiled.memory_analysis() is not None
+
+
+def test_one_model_fabric_pool_step_compiles_for_v5e_at_128_slots(one_chip):
+    """The fabric cell's pool step (one resident, 128 slots) compiles with
+    the kernel in it, and every instruction of the compiled step that the
+    program named lies under one of the step's scopes, so a trace reduction
+    can put each op in a stage. The compiler's own copies and layout ops
+    name nothing, or the parameter they copy; constants run nothing."""
+    from repro.core.cnn import compile_poker_cnn
+    from repro.serve.aer import AerServeConfig, AerSessionPool
+
+    pool = AerSessionPool.from_models(
+        {"tableV-3x3": compile_poker_cnn()}, AerServeConfig(pool_size=CELL_POOL),
+        backend="fabric", fabric_options={"interpret": False},
+    )
+    eng = pool.engine
+    assert eng.n_clusters == 6 and eng._fabric_entries.src.shape == (CELL_ENTRIES,)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    carry = jax.tree.map(sds, pool.carry)
+    inp = jax.ShapeDtypeStruct((CELL_POOL, eng.n_clusters, K), jnp.float32,
+                               sharding=one_chip)
+    text, compiled = _compile(eng.step, carry, inp)
+    assert "tpu_custom_call" in text
+    hlo = compiled.as_text()
+    assert re.search(r"%fabric_deliver(\.\d+)? = [^\n]*custom-call\(", hlo)
+    entry = re.search(r"\nENTRY [^\n]*\{\n(.*?)\n\}", hlo, re.S).group(1)
+    named = re.findall(r"%([\w.\-]+) = [^\n]*? (\w[\w\-]*)\([^\n]*op_name=\"([^\"]*)\"", entry)
+    params = {path for _, op, path in named if op == "parameter"}
+    assert params, "the step's parameters carry their names"
+    unscoped = [(name, path) for name, op, path in named
+                if op != "constant" and path not in params
+                and not STEP_SCOPES & set(re.split(r"[/;]", path))]
+    assert not unscoped
