@@ -203,7 +203,7 @@ def _stage1_activity(
         # capacity >= N makes the queue lossless AND makes compaction pure
         # overhead: the dense scatter visits the same nonzero entries in the
         # same (src, entry) order, adding only exact-0.0 terms for silent
-        # sources — bit-identical activity, zero drops, no cumsum/searchsorted
+        # sources — bit-identical activity, zero drops, no cumsum/scatter
         a = stage1_route(spikes, src_tag, src_dest, n_clusters, k_tags)
         dropped = jnp.zeros(spikes.shape[:-1], jnp.int32)
         return a, dropped

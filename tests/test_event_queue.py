@@ -14,6 +14,7 @@ The contract under test:
 """
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -92,6 +93,65 @@ def test_compact_batched_counts_per_stream():
 def test_compact_rejects_nonpositive_capacity():
     with pytest.raises(ValueError, match="capacity"):
         compact_events(jnp.zeros((8,)), 0)
+
+
+def test_compact_rejects_queues_past_int32_indexing():
+    """All streams' queues share one int32-indexed scatter; a batch whose
+    queues hold more slots than int32 counts is refused, not wrapped."""
+    spikes = jax.ShapeDtypeStruct((2**16, 2**15), jnp.float32)
+    with pytest.raises(ValueError, match="int32"):
+        jax.eval_shape(lambda s: compact_events(s, 2**15), spikes)
+
+
+def _compact_oracle(spikes: np.ndarray, capacity: int):
+    """The queue contract in plain numpy: the first ``capacity`` active ids of
+    each stream in ascending order, their weights, and the rest dropped."""
+    n = spikes.shape[-1]
+    q = min(capacity, n)
+    rows = spikes.reshape(-1, n)
+    src = np.full((len(rows), q), -1, np.int32)
+    weight = np.zeros((len(rows), q), spikes.dtype)
+    dropped = np.zeros(len(rows), np.int32)
+    for r, row in enumerate(rows):
+        active = np.flatnonzero(row != 0)
+        kept = active[:q]
+        src[r, : len(kept)] = kept
+        weight[r, : len(kept)] = row[kept]
+        dropped[r] = len(active) - len(kept)
+    batch = spikes.shape[:-1]
+    return src.reshape(*batch, q), weight.reshape(*batch, q), dropped.reshape(batch)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("batch", [(), (3,), (2, 3)], ids=["N", "BxN", "B1xB2xN"])
+@pytest.mark.parametrize("activity", ["none", "all", "mixed"])
+@pytest.mark.parametrize("capacity", ["one", "below_active", "n", "above_n"])
+def test_compact_matches_numpy_oracle_bit_for_bit(capacity, activity, batch, dtype):
+    """``src``, ``weight`` and ``dropped`` equal the oracle's bits for every
+    capacity, batch shape and dtype, with weights that are not 1."""
+    n = 24
+    rng = np.random.default_rng(11)
+    values = rng.choice(np.asarray([0.5, 2.0, -1.5, 3.25, 7.0]), (*batch, n))
+    mask = {
+        "none": np.zeros((*batch, n), bool),
+        "all": np.ones((*batch, n), bool),
+        "mixed": rng.random((*batch, n)) < 0.5,
+    }[activity]
+    if activity == "mixed" and batch:  # one silent and one saturated stream
+        mask.reshape(-1, n)[0] = False
+        mask.reshape(-1, n)[-1] = True
+    spikes = np.asarray(jnp.asarray(np.where(mask, values, 0.0), dtype))
+    cap = {"one": 1, "below_active": 5, "n": n, "above_n": n + 7}[capacity]
+
+    q = compact_events(jnp.asarray(spikes), cap)
+    src, weight, dropped = _compact_oracle(spikes, cap)
+    assert q.src.dtype == jnp.int32 and q.dropped.dtype == jnp.int32
+    assert q.weight.dtype == spikes.dtype
+    np.testing.assert_array_equal(np.asarray(q.src), src)
+    np.testing.assert_array_equal(
+        np.asarray(q.weight).view(np.uint8), weight.view(np.uint8)
+    )
+    np.testing.assert_array_equal(np.asarray(q.dropped), dropped)
 
 
 # ---------------------------------------------------------------------------

@@ -3,8 +3,9 @@
 The three delivery kernels and the jitted serving-pool step are compiled by
 the TPU compiler for a described v5e device at the served Table-V shapes
 (pool of 32 slots; 6 clusters for one resident model, 12 for two; 256
-neurons per cluster, K = 1024 tags, 64 CAM words), and the fabric kernel
-and pool step at the benchmark's fabric cell (128 slots, one resident).
+neurons per cluster, K = 1024 tags, 64 CAM words), the fabric kernel
+and pool step at the benchmark's fabric cell (128 slots, one resident),
+and the fused pool step at the fused cell (128 slots, two residents).
 Interpret mode accepts block layouts and VMEM footprints the chip refuses;
 these compiles do not.
 
@@ -27,7 +28,7 @@ from repro.kernels.fused_deliver.fused_deliver import fused_deliver_pallas
 
 POOL, C, K, S, E = 32, 256, 1024, 64, 16
 MAX_DELAY = 1  # the served fabric's delay horizon
-CELL_POOL = 128  # slots of the benchmark's tablev-3x3-fabric cell
+CELL_POOL = 128  # slots of both benchmark cells
 CELL_ENTRIES = 1280  # occupied SRAM entries of one Table-V CNN
 STEP_SCOPES = {"compact", "link_arbitration", "deliver", "neuron_update", "reset_slots"}
 
@@ -227,3 +228,39 @@ def test_one_model_fabric_pool_step_compiles_for_v5e_at_128_slots(one_chip):
                 if op != "constant" and path not in params
                 and not STEP_SCOPES & set(re.split(r"[/;]", path))]
     assert not unscoped
+
+
+def test_two_model_fused_pool_step_at_128_slots_has_no_compaction_loop(one_chip):
+    """The fused cell's pool step (two residents, nc 12, 128 slots) compiles
+    with the kernel in it, and the AER queue's compaction under scope
+    ``compact`` lowers to no loop (a ``while`` there would run a trip per
+    step of a search over every slot's queue) and keeps that scope on its
+    scatter."""
+    from repro.core.cnn import compile_poker_cnn
+    from repro.core.dispatch import FusedBackend
+    from repro.serve.aer import AerServeConfig, AerSessionPool
+
+    cc = compile_poker_cnn()
+    pool = AerSessionPool.from_models(
+        {"a": cc, "b": cc}, AerServeConfig(pool_size=CELL_POOL),
+        backend=FusedBackend(interpret=False),
+    )
+    eng = pool.engine
+    assert eng.n_clusters == 12 and eng.k_tags == K
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    carry = jax.tree.map(sds, pool.carry)
+    inp = jax.ShapeDtypeStruct((CELL_POOL, eng.n_clusters, K), jnp.float32,
+                               sharding=one_chip)
+    text, compiled = _compile(eng.step, carry, inp)
+    assert "tpu_custom_call" in text
+    hlo = compiled.as_text()
+    assert re.search(r"%fused_deliver(\.\d+)? = [^\n]*custom-call\(", hlo)
+    scoped = re.findall(r"%[\w.\-]+ = [^\n]*? (\w[\w\-]*)\([^\n]*op_name=\"([^\"]*)\"", hlo)
+    # the scatter keeps its scope, so a trace reduction counts it as compaction
+    assert any(op == "fusion" and path.endswith("/compact/scatter") for op, path in scoped)
+    loops = [path for op, path in scoped
+             if op == "while" and "compact" in re.split(r"[/;]", path)]
+    assert not loops
