@@ -62,7 +62,7 @@ def main():
     # verify two-stage delivery == dense connectivity on a random state
     dense = dense_weights_from_tables(tables)
     s = (rng.random(256) < 0.2).astype(np.float32)
-    from repro.core.two_stage import two_stage_deliver
+    from repro.core.dispatch import two_stage_deliver
 
     drive = two_stage_deliver(
         jnp.asarray(s), jnp.asarray(tables.src_tag), jnp.asarray(tables.src_dest),

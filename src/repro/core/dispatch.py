@@ -29,14 +29,13 @@ and scatter only queued events' SRAM entries in stage 1. ``with_stats=True``
 additionally returns a :class:`DeliveryStats` with the queue's drop counter
 (the chip's congestion behavior).
 
-Backends are selected by name through :func:`get_backend`; third-party
-backends can register via :func:`register_backend`.
+Backends are selected by name through :func:`get_backend` from the fixed
+set above.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import inspect
 
 import jax
 import jax.numpy as jnp
@@ -60,16 +59,10 @@ __all__ = [
     "ShardedBackend",
     "FabricBackend",
     "advance_inflight",
-    "register_backend",
     "get_backend",
     "available_backends",
-    "backend_deliver",
-    "AutotuneDecision",
-    "autotune_backend",
-    "autotune_candidates",
+    "two_stage_deliver",
 ]
-
-_REGISTRY: dict[str, type] = {}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -100,96 +93,6 @@ jax.tree_util.register_dataclass(
 )
 
 
-def register_backend(name: str):
-    """Class decorator: register a :class:`DispatchBackend` under ``name``."""
-
-    def deco(cls):
-        cls.name = name
-        _REGISTRY[name] = cls
-        return cls
-
-    return deco
-
-
-def available_backends() -> tuple[str, ...]:
-    return tuple(sorted(_REGISTRY))
-
-
-def get_backend(spec: str | DispatchBackend | None = "reference", **options) -> DispatchBackend:
-    """Resolve a backend by name (constructing it with ``options``) or pass
-    an already-constructed instance through unchanged."""
-    if isinstance(spec, DispatchBackend):
-        if options:
-            raise ValueError(
-                f"backend options {sorted(options)} ignored: {spec.name!r} was "
-                "passed as an instance — configure it at construction instead"
-            )
-        return spec
-    if spec is None:
-        spec = "reference"
-    try:
-        cls = _REGISTRY[spec]
-    except KeyError:
-        raise ValueError(
-            f"unknown dispatch backend {spec!r}; available: {available_backends()}"
-        ) from None
-    return cls(**options)
-
-
-def _kwargs_accepted_by(fn) -> set[str] | None:
-    """Names ``fn`` accepts as keywords; ``None`` means it takes ``**kwargs``."""
-    sig = inspect.signature(fn)
-    if any(p.kind is inspect.Parameter.VAR_KEYWORD for p in sig.parameters.values()):
-        return None
-    return set(sig.parameters)
-
-
-def backend_deliver(
-    backend: DispatchBackend,
-    spikes: jax.Array,
-    src_tag: jax.Array,
-    src_dest: jax.Array,
-    cam_tag: jax.Array,
-    cam_syn: jax.Array,
-    cluster_size: int,
-    k_tags: int,
-    external_activity: jax.Array | None = None,
-    queue_capacity: int | None = None,
-    syn_onehot: jax.Array | None = None,
-    with_stats: bool = False,
-):
-    """Signature-tolerant ``deliver`` call (the engine/two_stage entry point).
-
-    Third-party backends registered against the pre-§10 interface (no
-    ``queue_capacity`` / ``syn_onehot`` / ``with_stats`` keywords) keep
-    working: the new kwargs are forwarded only when the backend accepts
-    them. ``syn_onehot`` is a pure optimization hint and is dropped
-    silently; ``with_stats`` is synthesized (zero drops — a legacy backend
-    is always dense); asking a legacy backend for ``queue_capacity`` is a
-    semantic request it cannot honor and raises.
-    """
-    accepted = _kwargs_accepted_by(backend.deliver)
-    kwargs = {"external_activity": external_activity}
-    for name, value in (
-        ("queue_capacity", queue_capacity),
-        ("syn_onehot", syn_onehot),
-        ("with_stats", with_stats),
-    ):
-        if accepted is None or name in accepted:
-            kwargs[name] = value
-        elif name == "queue_capacity" and queue_capacity is not None:
-            raise ValueError(
-                f"dispatch backend {backend.name!r} predates event-sparse "
-                "delivery and does not support queue_capacity"
-            )
-    out = backend.deliver(
-        spikes, src_tag, src_dest, cam_tag, cam_syn, cluster_size, k_tags, **kwargs
-    )
-    if with_stats and "with_stats" not in kwargs:
-        return out, DeliveryStats(dropped=jnp.zeros(spikes.shape[:-1], jnp.int32))
-    return out
-
-
 def _stage1_activity(
     spikes: jax.Array,
     src_tag: jax.Array,
@@ -214,8 +117,6 @@ def _stage1_activity(
 
 class DispatchBackend:
     """Interface: batched stage-1 scatter shared, stage-2 pluggable."""
-
-    name = "abstract"
 
     # -- stage 2 -----------------------------------------------------------
     def cam_match(
@@ -249,20 +150,12 @@ class DispatchBackend:
         )
         if external_activity is not None:
             a = a + external_activity
-        # forward the one-hot hint only to stage-2 hooks that know it (a
-        # subclass written against the pre-§10 cam_match signature still works)
-        accepted = _kwargs_accepted_by(self.cam_match)
-        cam_kwargs = (
-            {"syn_onehot": syn_onehot} if accepted is None or "syn_onehot" in accepted
-            else {}
-        )
-        drive = self.cam_match(a, cam_tag, cam_syn, cluster_size, **cam_kwargs)
+        drive = self.cam_match(a, cam_tag, cam_syn, cluster_size, syn_onehot)
         if with_stats:
             return drive, DeliveryStats(dropped=dropped)
         return drive
 
 
-@register_backend("reference")
 @dataclasses.dataclass(frozen=True)
 class ReferenceBackend(DispatchBackend):
     """Pure-jnp stage 2 (direct indexed gather + synapse-type einsum)."""
@@ -271,7 +164,6 @@ class ReferenceBackend(DispatchBackend):
         return stage2_cam_match(activity, cam_tag, cam_syn, cluster_size, syn_onehot)
 
 
-@register_backend("pallas")
 @dataclasses.dataclass(frozen=True)
 class PallasBackend(DispatchBackend):
     """Stage 2 on the kernels/cam_match Pallas kernel.
@@ -305,7 +197,6 @@ class PallasBackend(DispatchBackend):
         )
 
 
-@register_backend("fused")
 @dataclasses.dataclass(frozen=True)
 class FusedBackend(DispatchBackend):
     """Single-kernel delivery: stage-1 scatter + stage-2 CAM match fused.
@@ -384,7 +275,6 @@ def advance_inflight(buffer, inflight, max_delay: int):
     return a, shifted + buffer[..., 1:, :, :]
 
 
-@register_backend("fabric")
 class FabricBackend(DispatchBackend):
     """Latency/bandwidth-aware delivery over the R1/R2/R3 fabric (§11).
 
@@ -397,7 +287,7 @@ class FabricBackend(DispatchBackend):
 
     Two entry points:
 
-    * :meth:`deliver` (the registry API) models one *isolated* timestep:
+    * :meth:`deliver` (the backend interface) models one *isolated* timestep:
       link capacity and the hop/latency/energy accounting apply, but with no
       delay line to thread the buffer is collapsed — every surviving event
       is delivered in the same step ("zero-warp" statistical mode). With
@@ -521,25 +411,6 @@ class FabricBackend(DispatchBackend):
         model, _ = self.model_for(n_clusters)
         return fabric_ops.build_fabric_entries(
             src_tag, src_dest, cluster_size, k_tags, model
-        )
-
-    def build_entries_slabs(
-        self, per_model, cluster_size: int, k_tags: int
-    ):
-        """Multi-model entry table as slab-offset concatenation (§16).
-
-        ``per_model`` is a sequence of per-resident ``(src_tag, src_dest)``
-        pairs laid out back to back; the combined cluster count is derived
-        from the total neuron count. Bit-identical to :meth:`build_entries`
-        on the concatenated tables — see
-        kernels/fabric_deliver/ops.build_fabric_entries_slabs.
-        """
-        from repro.kernels.fabric_deliver import ops as fabric_ops
-
-        n_total = sum(np.asarray(st).shape[0] for st, _ in per_model)
-        model, _ = self.model_for(n_total // cluster_size)
-        return fabric_ops.build_fabric_entries_slabs(
-            per_model, cluster_size, k_tags, model
         )
 
     def entry_alive_for(self, src_tag, src_dest, cluster_size: int):
@@ -744,7 +615,6 @@ def sharded_local_deliver(
     return drive
 
 
-@register_backend("sharded")
 class ShardedBackend(DispatchBackend):
     """Full delivery under shard_map on a 2-D (batch, cluster) mesh.
 
@@ -840,154 +710,74 @@ class ShardedBackend(DispatchBackend):
         return drive
 
 
-# ---------------------------------------------------------------------------
-# dispatch autotuner — measured dense/queued/fused crossover (DESIGN.md §18)
-# ---------------------------------------------------------------------------
-@dataclasses.dataclass(frozen=True)
-class AutotuneDecision:
-    """Outcome of one :func:`autotune_backend` pass.
-
-    ``winner`` is the measured-fastest candidate; ``backend`` / ``dense``
-    are how the engine realizes it (registry backend name + whether the AER
-    queue compaction is bypassed — the dense path still reports zero-drop
-    stats, so the step's output contract is unchanged). ``measurements``
-    records every candidate's best-of-``iters`` wall time in µs, in
-    canonical candidate order, so the decision is auditable and the engine
-    fingerprint can carry it.
-    """
-
-    winner: str
-    backend: str
-    dense: bool
-    activity: float
-    batch: int
-    measurements: tuple[tuple[str, float], ...]
-
-    def token(self) -> str:
-        """Compact fingerprint component (decision, not timings)."""
-        return f"autotune:{self.winner}:act{self.activity:g}:B{self.batch}"
-
-
-# candidate -> (registry backend, bypass queue compaction)
-_AUTOTUNE_IMPL = {
-    "dense": ("reference", True),
-    "queued": ("reference", False),
-    "fused": ("fused", False),
-    # fabric_ring is measurable only via an injected measurement (timing it
-    # needs a ring carry); it maps onto the fabric backend's default mode
-    "fabric_ring": ("fabric", False),
+_BACKENDS: dict[str, type[DispatchBackend]] = {
+    "reference": ReferenceBackend,
+    "pallas": PallasBackend,
+    "fused": FusedBackend,
+    "sharded": ShardedBackend,
+    "fabric": FabricBackend,
 }
 
 
-def autotune_candidates() -> tuple[str, ...]:
-    return tuple(_AUTOTUNE_IMPL)
+def available_backends() -> tuple[str, ...]:
+    return tuple(sorted(_BACKENDS))
 
 
-def autotune_backend(
-    src_tag,
-    src_dest,
-    cam_tag,
-    cam_syn,
+def get_backend(spec: str | DispatchBackend | None = "reference", **options) -> DispatchBackend:
+    """Resolve a backend by name (constructing it with ``options``) or pass
+    an already-constructed instance through unchanged."""
+    if isinstance(spec, DispatchBackend):
+        if options:
+            raise ValueError(
+                f"backend options {sorted(options)} ignored: "
+                f"{type(spec).__name__} was passed as an instance — configure "
+                "it at construction instead"
+            )
+        return spec
+    if spec is None:
+        spec = "reference"
+    try:
+        cls = _BACKENDS[spec]
+    except KeyError:
+        raise ValueError(
+            f"unknown dispatch backend {spec!r}; available: {available_backends()}"
+        ) from None
+    return cls(**options)
+
+
+def two_stage_deliver(
+    spikes: jax.Array,
+    src_tag: jax.Array,
+    src_dest: jax.Array,
+    cam_tag: jax.Array,
+    cam_syn: jax.Array,
     cluster_size: int,
     k_tags: int,
-    *,
-    activity: float = 0.1,
-    batch: int = 8,
+    external_activity: jax.Array | None = None,
+    backend: str | DispatchBackend = "reference",
     queue_capacity: int | None = None,
-    candidates: tuple[str, ...] = ("dense", "queued", "fused"),
-    measure: dict[str, float] | None = None,
-    iters: int = 3,
-    seed: int = 0,
-    tol: float = 0.05,
-) -> AutotuneDecision:
-    """Measure the dense/queued/fused crossover at one (activity, B) point.
+    syn_onehot: jax.Array | None = None,
+    with_stats: bool = False,
+):
+    """Full event delivery: spikes -> synaptic drive per neuron & synapse type.
 
-    Times each candidate's jitted delivery on a deterministic synthetic
-    spike batch (``batch`` streams at ``activity`` fraction active, drawn
-    from ``seed``) and returns the winner as an :class:`AutotuneDecision`.
-    ``measure`` injects known timings per candidate (µs) — injected
-    candidates are not re-timed, so a fully-injected call is deterministic
-    and timing-free (the conformance tests use this, and benchmarks use it
-    to add a ``fabric_ring`` figure measured elsewhere). The winner is the
-    *earliest* candidate within ``tol`` of the measured fastest, not the
-    strict argmin: at a genuine crossover two candidates time equal and
-    wall-clock jitter would flip the argmin between runs, whereas the
-    noise band makes the decision stable (and exact ties break in
-    ``candidates`` order either way).
-
-    ``queue_capacity`` should be the engine's actual queue depth: the
-    queued candidate is measured under exactly the compaction the engine
-    would run. With ``None`` (or a capacity at/above the event count) the
-    queued path degenerates to dense — the lossless-queue shortcut — so
-    the tuner records dense's timing for it instead of racing two
-    timings of the same program, and the dead heat resolves to ``dense``
-    by construction.
+    ``external_activity`` injects input events (the chip's Input Interface /
+    FPGA path) directly as tag activity. ``backend`` selects the dispatch
+    implementation by name or instance (:func:`get_backend`).
+    ``queue_capacity`` enables event-sparse delivery through a fixed-capacity
+    AER queue (DESIGN.md §10); with ``with_stats=True`` the return value is
+    ``(drive, DeliveryStats)`` carrying the queue's drop counter.
     """
-    import time as _time
-
-    for cand in candidates:
-        if cand not in _AUTOTUNE_IMPL:
-            raise ValueError(
-                f"unknown autotune candidate {cand!r}; known: {autotune_candidates()}"
-            )
-    measure = dict(measure or {})
-    timed = [c for c in candidates if c not in measure]
-    if timed:
-        n = src_tag.shape[0]
-        rng = np.random.default_rng(seed)
-        spikes = jnp.asarray(
-            (rng.random((int(batch), n)) < float(activity)).astype(np.float32)
-        )
-        st, sd = jnp.asarray(src_tag), jnp.asarray(src_dest)
-        ct, cs = jnp.asarray(cam_tag), jnp.asarray(cam_syn)
-        from repro.core.two_stage import precompute_syn_onehot
-
-        onehot = precompute_syn_onehot(cs)
-        # a lossless queue (capacity at/above the event count) makes the
-        # queued path computationally identical to dense — don't race two
-        # timings of the same program (a dead heat any load spike can flip):
-        # record dense's figure for queued after the loop
-        lossless = queue_capacity is None or int(queue_capacity) >= n
-        alias_queued = (
-            lossless and "queued" in timed
-            and ("dense" in measure or "dense" in timed)
-        )
-        for cand in timed:
-            if cand == "queued" and alias_queued:
-                continue
-            if cand == "fabric_ring":
-                raise ValueError(
-                    "fabric_ring can only be autotuned via an injected "
-                    "measurement (measure={'fabric_ring': us})"
-                )
-            bname, dense = _AUTOTUNE_IMPL[cand]
-            be = get_backend(bname)
-            qc = None if dense else queue_capacity
-
-            def fn(s, _be=be, _qc=qc):
-                return backend_deliver(
-                    _be, s, st, sd, ct, cs, cluster_size, k_tags,
-                    queue_capacity=_qc, syn_onehot=onehot,
-                )
-
-            jfn = jax.jit(fn)
-            jfn(spikes).block_until_ready()  # compile + warm outside timing
-            best = float("inf")
-            for _ in range(max(1, int(iters))):
-                t0 = _time.perf_counter()
-                jfn(spikes).block_until_ready()
-                best = min(best, _time.perf_counter() - t0)
-            measure[cand] = best * 1e6
-        if alias_queued:
-            measure["queued"] = measure["dense"]
-    best = min(measure[c] for c in candidates)
-    winner = next(c for c in candidates if measure[c] <= (1.0 + tol) * best)
-    backend, dense = _AUTOTUNE_IMPL[winner]
-    return AutotuneDecision(
-        winner=winner,
-        backend=backend,
-        dense=dense,
-        activity=float(activity),
-        batch=int(batch),
-        measurements=tuple((c, float(measure[c])) for c in candidates),
+    return get_backend(backend).deliver(
+        spikes,
+        src_tag,
+        src_dest,
+        cam_tag,
+        cam_syn,
+        cluster_size,
+        k_tags,
+        external_activity=external_activity,
+        queue_capacity=queue_capacity,
+        syn_onehot=syn_onehot,
+        with_stats=with_stats,
     )

@@ -65,12 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import neuron as neuron_mod
-from repro.core.dispatch import (
-    DeliveryStats,
-    DispatchBackend,
-    backend_deliver,
-    get_backend,
-)
+from repro.core.dispatch import DeliveryStats, DispatchBackend, get_backend
 from repro.core.neuron import NeuronParams, NeuronState
 from repro.core.shard_compat import SM_CHECK_KW, shard_map
 from repro.core.tags import RoutingTables
@@ -144,12 +139,10 @@ class EventEngine:
         params: NeuronParams | None = None,
         backend: str | DispatchBackend = "reference",
         backend_options: dict | None = None,
-        autotune: dict | None = None,  # backend="auto" kwargs / {"decision": ...}
         queue_capacity: int | None = None,
         donate_carry: bool = False,
         fabric=None,  # routing.Fabric | dispatch.FabricBackend | None
         fabric_options: dict | None = None,
-        entry_slabs=None,  # multi-model ring fast path: [(src_tag_m, src_dest_m)]
     ):
         # a compiler-v2 CompileResult (core/compiler.py) carries the tables
         # plus a CompileReport; unwrap it so optimized placements flow
@@ -164,45 +157,6 @@ class EventEngine:
         if queue_capacity is not None and queue_capacity <= 0:
             raise ValueError(f"queue_capacity must be positive, got {queue_capacity}")
         self.queue_capacity = queue_capacity
-        # dispatch autotuner (DESIGN.md §18): backend="auto" measures the
-        # dense/queued/fused crossover at this engine's (activity, B) point —
-        # or honors an injected AutotuneDecision — and builds the winner.
-        # ``dense`` winners bypass queue compaction in the step while keeping
-        # the (spikes, stats) output contract (stats read zero drops).
-        self.autotune_decision = None
-        self._autotune_dense = False
-        if backend == "auto":
-            if fabric is not None:
-                raise ValueError(
-                    "backend='auto' tunes the dense/queued/fused dispatch "
-                    "path; fabric engines deliver through the fabric model — "
-                    "pass an explicit backend"
-                )
-            from repro.core.dispatch import autotune_backend
-
-            opts = dict(autotune or {})
-            decision = opts.pop("decision", None)
-            if decision is None:
-                opts.setdefault("queue_capacity", queue_capacity)
-                decision = autotune_backend(
-                    tables.src_tag,
-                    tables.src_dest,
-                    tables.cam_tag,
-                    tables.cam_syn,
-                    self.cluster_size,
-                    self.k_tags,
-                    **opts,
-                )
-            elif opts:
-                raise ValueError(
-                    "autotune={'decision': ...} is exclusive with tuning "
-                    f"options {sorted(opts)}"
-                )
-            self.autotune_decision = decision
-            backend = decision.backend
-            self._autotune_dense = bool(decision.dense)
-        elif autotune:
-            raise ValueError("autotune options require backend='auto'")
         self.backend = get_backend(backend, **(backend_options or {}))
         # fabric mode (DESIGN.md §11): delivery runs on a FabricBackend and
         # the step carry gains the in-flight delay-line buffer; cross-tile
@@ -276,27 +230,8 @@ class EventEngine:
         )
         self._fabric_entries = None
         if self.fabric_ring:
-            if entry_slabs is not None:
-                # multi-model residency (DESIGN.md §16): the static entry
-                # table is assembled slab-by-slab with slab-offset
-                # addressing — bit-identical to building from the
-                # concatenated table (tests/test_multimodel.py locks it)
-                n_total = sum(np.asarray(st).shape[0] for st, _ in entry_slabs)
-                if n_total != self.n_neurons:
-                    raise ValueError(
-                        f"entry_slabs span {n_total} neurons, tables have "
-                        f"{self.n_neurons}"
-                    )
-                self._fabric_entries = self.fabric_backend.build_entries_slabs(
-                    entry_slabs, self.cluster_size, self.k_tags
-                )
-            else:
-                self._fabric_entries = self.fabric_backend.build_entries(
-                    tables.src_tag, tables.src_dest, self.cluster_size, self.k_tags
-                )
-        elif entry_slabs is not None:
-            raise ValueError(
-                "entry_slabs only applies to the fabric ring fast path"
+            self._fabric_entries = self.fabric_backend.build_entries(
+                tables.src_tag, tables.src_dest, self.cluster_size, self.k_tags
             )
         # per-engine compiled step (self is closed over = static). Carry
         # donation is opt-in: with donate_carry=True on an accelerator the
@@ -417,8 +352,7 @@ class EventEngine:
             return (state, spikes, inflight), (spikes, stats)
         state, prev_spikes = carry
         with jax.named_scope("deliver"):
-            drive, stats = backend_deliver(
-                self.backend,
+            drive, stats = self.backend.deliver(
                 prev_spikes,
                 self.tables.src_tag,
                 self.tables.src_dest,
@@ -427,9 +361,7 @@ class EventEngine:
                 self.cluster_size,
                 self.k_tags,
                 external_activity=input_activity,
-                # an autotuned "dense" winner bypasses compaction; the output
-                # contract still follows queue_capacity (stats read zero drops)
-                queue_capacity=None if self._autotune_dense else self.queue_capacity,
+                queue_capacity=self.queue_capacity,
                 syn_onehot=self.tables.cam_syn_onehot,
                 with_stats=True,
             )

@@ -20,8 +20,8 @@ For alpha=1:   MEM   = 2*sqrt(F*log2(C)*log2(N))                       (eq. 6)
 Conventional (source/destination-addressed) routing: F*log2(N) bits/neuron.
 
 These are pure functions of python/numpy scalars: they are used by the network
-compiler to size tables, by benchmarks to reproduce Fig. 13, and by tests
-(hypothesis) to verify optimality and the Appendix-A feasibility constraints.
+compiler to size tables, and by tests (hypothesis) to verify optimality and
+the Appendix-A feasibility constraints.
 """
 
 from __future__ import annotations

@@ -51,7 +51,6 @@ import jax.numpy as jnp
 __all__ = [
     "stage1_route",
     "stage2_cam_match",
-    "two_stage_deliver",
     "compact_events",
     "stage1_route_events",
     "stage1_route_events_fabric",
@@ -548,47 +547,6 @@ def stage2_cam_match(
         precision=jax.lax.Precision.HIGHEST,
     )
     return out.reshape(*batch_shape, n, N_SYN_TYPES)
-
-
-def two_stage_deliver(
-    spikes: jax.Array,
-    src_tag: jax.Array,
-    src_dest: jax.Array,
-    cam_tag: jax.Array,
-    cam_syn: jax.Array,
-    cluster_size: int,
-    k_tags: int,
-    external_activity: jax.Array | None = None,
-    backend: str | object = "reference",
-    queue_capacity: int | None = None,
-    syn_onehot: jax.Array | None = None,
-    with_stats: bool = False,
-):
-    """Full event delivery: spikes -> synaptic drive per neuron & synapse type.
-
-    ``external_activity`` injects input events (the chip's Input Interface /
-    FPGA path) directly as tag activity. ``backend`` selects the dispatch
-    implementation by name or instance (core/dispatch.py registry).
-    ``queue_capacity`` enables event-sparse delivery through a fixed-capacity
-    AER queue (DESIGN.md §10); with ``with_stats=True`` the return value is
-    ``(drive, DeliveryStats)`` carrying the queue's drop counter.
-    """
-    from repro.core.dispatch import backend_deliver, get_backend
-
-    return backend_deliver(
-        get_backend(backend),
-        spikes,
-        src_tag,
-        src_dest,
-        cam_tag,
-        cam_syn,
-        cluster_size,
-        k_tags,
-        external_activity=external_activity,
-        queue_capacity=queue_capacity,
-        syn_onehot=syn_onehot,
-        with_stats=with_stats,
-    )
 
 
 # ---------------------------------------------------------------------------

@@ -122,7 +122,7 @@ def ef_all_reduce(
 
 
 # ---------------------------------------------------------------------------
-# byte accounting (used by benchmarks + EXPERIMENTS.md §Perf napkin math)
+# byte accounting (EXPERIMENTS.md §Perf napkin math)
 # ---------------------------------------------------------------------------
 def all_reduce_cross_pod_bytes(
     n_bytes: int, n_pods: int, in_pod_size: int, hierarchical: bool

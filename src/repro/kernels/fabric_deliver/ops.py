@@ -50,7 +50,6 @@ from repro.kernels.fabric_deliver.fabric_deliver import fabric_deliver_ring_pall
 __all__ = [
     "FabricEntries",
     "build_fabric_entries",
-    "build_fabric_entries_slabs",
     "fabric_deliver_ring",
 ]
 
@@ -129,36 +128,6 @@ def build_fabric_entries(
     alive = (
         None if entry_alive is None else np.asarray(entry_alive)[src_ids, e_ids]
     )
-    return _entries_from_raw(
-        src_ids, e_ids, tag, dst, cluster_size, k_tags, model, alive
-    )
-
-
-def _pad_entries() -> FabricEntries:
-    """One inert pad row for an entry-less table."""
-    z = np.zeros(1, np.int32)
-    return FabricEntries(
-        src=jnp.asarray(z), dstk=jnp.asarray(z), delay=jnp.asarray(z),
-        cross=jnp.asarray(np.zeros(1, bool)), link_start=jnp.asarray(z),
-        link=jnp.asarray(z),
-        hops=jnp.asarray(z), latency_s=jnp.zeros(1, jnp.float32),
-        energy_j=jnp.zeros(1, jnp.float32),
-        valid=jnp.asarray(np.zeros(1, bool)),
-        alive=jnp.asarray(np.ones(1, bool)),
-    )
-
-
-def _entries_from_raw(
-    src_ids, e_ids, tag, dst, cluster_size, k_tags, model, alive
-) -> FabricEntries:
-    """Arbitration-order sort + static per-entry figures from raw entry rows.
-
-    ``src_ids``/``e_ids`` must arrive in row-major table order (src asc,
-    entry asc) — both the dense ``np.nonzero`` path and the slab
-    concatenation produce exactly that, so the stable lexsort yields one
-    canonical arbitration order regardless of how the rows were enumerated.
-    """
-    tiles = np.asarray(model.tile_of_cluster)
     src_cl = src_ids // cluster_size
     s_tile = tiles[src_cl]
     d_tile = tiles[dst]
@@ -198,61 +167,17 @@ def _entries_from_raw(
     )
 
 
-def build_fabric_entries_slabs(
-    per_model,  # sequence of (src_tag_m [N_m, E_m], src_dest_m [N_m, E_m])
-    cluster_size: int,
-    k_tags: int,  # the COMBINED table's K (flat dstk addressing)
-    model,  # routing.FabricDeliveryModel over the combined cluster count
-) -> FabricEntries:
-    """Entry table for N resident models as slab-offset concatenation.
-
-    Builds the multi-model ring fast path's static table directly from the
-    per-model slabs: each model's raw entry rows are rebased by its slab's
-    neuron/cluster offsets (slabs are laid out back to back, in order), then
-    a single global arbitration sort merges them — models share the physical
-    link FIFOs, so each directed link's group interleaves every model's
-    entries in source-id order. Bit-identical to :func:`build_fabric_entries`
-    on the concatenated table (``tags.concat_tables``): slab enumeration
-    yields the same row-major entry sequence, and the stable lexsort is
-    order-canonical (the conformance test in tests/test_multimodel.py locks
-    this).
-
-    Fault masks are drawn over the full table grid, so a faulted ``model``
-    must go through the concatenated-table path instead.
-    """
-    if getattr(model, "pair_alive", None) is not None:
-        raise ValueError(
-            "build_fabric_entries_slabs does not support fault injection — "
-            "build from the concatenated tables (build_fabric_entries) so "
-            "the route-erasure draw sees the full table grid"
-        )
-    srcs, ents, tags, dsts = [], [], [], []
-    n0 = 0
-    nc = np.asarray(model.tile_of_cluster).shape[0]
-    for src_tag_m, src_dest_m in per_model:
-        src_tag_m = np.asarray(src_tag_m)
-        src_dest_m = np.asarray(src_dest_m)
-        c0 = n0 // cluster_size
-        s_m, e_m = np.nonzero(src_tag_m >= 0)
-        srcs.append(s_m + n0)
-        ents.append(e_m)
-        tags.append(src_tag_m[s_m, e_m].astype(np.int64))
-        dsts.append(
-            np.clip(src_dest_m[s_m, e_m] + c0, 0, nc - 1).astype(np.int64)
-        )
-        n0 += src_tag_m.shape[0]
-    src_ids = np.concatenate(srcs) if srcs else np.zeros(0, np.int64)
-    if src_ids.size == 0:
-        return _pad_entries()
-    return _entries_from_raw(
-        src_ids,
-        np.concatenate(ents),
-        np.concatenate(tags),
-        np.concatenate(dsts),
-        cluster_size,
-        k_tags,
-        model,
-        None,
+def _pad_entries() -> FabricEntries:
+    """One inert pad row for an entry-less table."""
+    z = np.zeros(1, np.int32)
+    return FabricEntries(
+        src=jnp.asarray(z), dstk=jnp.asarray(z), delay=jnp.asarray(z),
+        cross=jnp.asarray(np.zeros(1, bool)), link_start=jnp.asarray(z),
+        link=jnp.asarray(z),
+        hops=jnp.asarray(z), latency_s=jnp.zeros(1, jnp.float32),
+        energy_j=jnp.zeros(1, jnp.float32),
+        valid=jnp.asarray(np.zeros(1, bool)),
+        alive=jnp.asarray(np.ones(1, bool)),
     )
 
 

@@ -3,7 +3,7 @@
 Defined as functions (never module-level constants) so importing this module
 never touches jax device state. The dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` BEFORE any jax
-import; smoke tests and benchmarks see the real (1-device) CPU.
+import; smoke tests see the real (1-device) CPU.
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 """Process-level JAX set-up shared by the entry points.
 
 ``chip_smoke.py``, ``examples/poker_dvs_serve.py``, ``examples/sharded_serve.py``
-and ``benchmarks/run.py`` call these before their first computation.
+and ``bench/`` call these before their first computation.
 Importing this module does not import JAX: :func:`fake_host_devices` has to
 run before JAX starts its backend.
 """

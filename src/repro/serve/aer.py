@@ -161,17 +161,16 @@ def build_poker_engine(
     backend: str = "reference",
     donate_carry: bool = True,
     faults=None,
-    entry_slabs=None,
     fabric_options: dict | None = None,
-    autotune: dict | None = None,
 ) -> EventEngine:
     """Event engine at the §V serving operating point for a dispatch backend.
 
-    ``backend`` is any registry name (reference / pallas / fused / sharded)
-    or ``"fabric"`` for executable-mesh delivery on the default 3x3-chip
-    board geometry. The AER queue is sized lossless for this workload.
-    Shared by examples/poker_dvs_serve.py and benchmarks/serving.py so both
-    measure the same engine.
+    ``backend`` is a built-in backend name (reference / pallas / fused /
+    sharded) or ``"fabric"`` for executable-mesh delivery on the default
+    3x3-chip board geometry. The AER queue is sized lossless for this
+    workload.
+    Every served pool builds its engine here (``AerSessionPool.from_models``),
+    so examples, chip scripts and the benchmark run the same engine.
 
     Serving flips the engine's conservative ``donate_carry`` default to
     ``True``: the pool always threads the returned carry and never re-reads
@@ -190,26 +189,21 @@ def build_poker_engine(
         opts = dict(fabric_options or {})
         if faults is not None:
             opts["faults"] = faults
-        if autotune is not None:
-            raise ValueError("autotune applies to backend='auto', not fabric")
         return EventEngine(
             tables, params, queue_capacity=q_cap, fabric=Fabric(),
             donate_carry=donate_carry, fabric_options=opts,
-            entry_slabs=entry_slabs,
         )
     if faults is not None:
         raise ValueError(
             f"fault injection needs the fabric backend, got {backend!r}"
         )
-    if entry_slabs is not None:
-        raise ValueError("entry_slabs only applies to the fabric backend")
     if fabric_options is not None:
         raise ValueError(
             f"fabric_options need the fabric backend, got {backend!r}"
         )
     return EventEngine(
         tables, params, backend=backend, queue_capacity=q_cap,
-        donate_carry=donate_carry, autotune=autotune,
+        donate_carry=donate_carry,
     )
 
 
@@ -379,30 +373,12 @@ class AerSessionPool:
     # -- multi-model residency (DESIGN.md §16) -----------------------------
     @staticmethod
     def _engine_for(models: dict[str, CompiledCnn], engine_kw: dict) -> EventEngine:
-        """One engine over the concatenated slabs of every resident model.
-
-        In fabric-ring mode the static entry table is assembled slab-by-slab
-        (slab-offset addressing); fault injection needs the full-grid
-        Bernoulli draw, so faulted engines build from the concatenated table
-        instead — the two constructions are bit-identical. The roll-carried
-        fabric path (``fabric_options={"ring": False}``) has no entry table.
-        """
+        """One engine over the concatenated slabs of every resident model."""
         registry = ModelRegistry(
             {name: m.tables for name, m in models.items()}
         )
         combined, _ = registry.combined()
-        entry_slabs = None
-        if (
-            len(models) > 1
-            and engine_kw.get("backend") == "fabric"
-            and engine_kw.get("faults") is None
-            and (engine_kw.get("fabric_options") or {}).get("ring", True)
-        ):
-            entry_slabs = [
-                (t.src_tag, t.src_dest)
-                for t in (registry.tables_of(n) for n in registry.names)
-            ]
-        return build_poker_engine(combined, entry_slabs=entry_slabs, **engine_kw)
+        return build_poker_engine(combined, **engine_kw)
 
     @classmethod
     def from_models(
@@ -414,7 +390,6 @@ class AerSessionPool:
         donate_carry: bool = True,
         faults=None,
         fabric_options: dict | None = None,
-        autotune: dict | None = None,
     ) -> "AerSessionPool":
         """Pool with N resident models sharing one engine, hot-swap enabled.
 
@@ -426,8 +401,7 @@ class AerSessionPool:
 
         ``fabric_options`` configures the fabric backend (e.g.
         ``{"per_link_stats": True, "link_capacity": k}`` for the observed-
-        traffic feedback loop of DESIGN.md §18); ``autotune`` configures
-        ``backend="auto"`` (see :class:`repro.core.event_engine.EventEngine`).
+        traffic feedback loop of DESIGN.md §18).
         """
         if not models:
             raise ValueError("from_models needs at least one resident model")
@@ -436,7 +410,6 @@ class AerSessionPool:
             "donate_carry": donate_carry,
             "faults": faults,
             "fabric_options": fabric_options,
-            "autotune": autotune,
         }
         engine = cls._engine_for(models, engine_kw)
         first = next(iter(models.values()))
@@ -456,11 +429,6 @@ class AerSessionPool:
         h = hashlib.sha256()
         h.update(self.registry.fingerprint().encode())
         h.update(f"|{mode}|P{self.cfg.pool_size}".encode())
-        decision = getattr(self.engine, "autotune_decision", None)
-        if decision is not None:
-            # the autotuned dispatch choice is part of the serving geometry:
-            # a restore onto a differently-tuned engine is a real mismatch
-            h.update(f"|{decision.token()}".encode())
         return h.hexdigest()
 
     def _resolve_model(self, session: DvsSession) -> str:
@@ -484,9 +452,8 @@ class AerSessionPool:
 
         In-flight sessions keep running: their slots are migrated onto the
         rebuilt engine (slab slice -> fresh-init embed -> splice), readout
-        accumulators untouched. The rebuild recompiles once — that cost is
-        the ``multimodel_load_overhead`` row in BENCH_routing.json; steady-
-        state serving of the grown pool never recompiles again.
+        accumulators untouched. The rebuild recompiles once; steady-state
+        serving of the grown pool never recompiles again.
         """
         if self._engine_kw is None:
             raise RuntimeError(

@@ -134,7 +134,6 @@ def build_poker_shard_engine(
     batch_devices: int = 1,
     devices=None,
     donate_carry: bool = True,
-    entry_slabs=None,
     per_link_stats: bool = False,
 ) -> ShardedEventEngine:
     """One serving shard's engine at the §V poker operating point.
@@ -167,12 +166,9 @@ def build_poker_shard_engine(
             tables,
             params,
             fabric=Fabric(),
-            entry_slabs=entry_slabs,
             fabric_options=fabric_options,
             **mesh_kw,
         )
-    if entry_slabs is not None:
-        raise ValueError("entry_slabs only applies to the fabric backend")
     if per_link_stats:
         raise ValueError("per_link_stats only applies to the fabric backend")
     return ShardedEventEngine(tables, params, backend=backend, **mesh_kw)
@@ -225,23 +221,15 @@ class ShardedSessionPool:
             dict(models) if models else {"default": cc}
         )
         self._shard_devices = self._assign_devices(devices)
-        entry_slabs = None
         if len(self.models) == 1:
             eng_tables = next(iter(self.models.values())).tables
         else:
             # multi-model: one engine over the concatenated slabs (fabric
-            # multi-model over cluster shards is rejected above); in fabric
-            # mode the entry table is assembled slab-by-slab, mirroring
-            # AerSessionPool._engine_for
+            # multi-model over cluster shards is rejected above)
             registry = ModelRegistry(
                 {n: m.tables for n, m in self.models.items()}
             )
             eng_tables, _ = registry.combined()
-            if shards.backend == "fabric":
-                entry_slabs = [
-                    (t.src_tag, t.src_dest)
-                    for t in (registry.tables_of(n) for n in registry.names)
-                ]
         self.pools: list[AerSessionPool | None] = []
         for i in range(shards.n_shards):
             if engine_factory is not None:
@@ -253,7 +241,6 @@ class ShardedSessionPool:
                     cluster_devices=shards.cluster_devices,
                     batch_devices=shards.batch_devices,
                     devices=self._shard_devices[i],
-                    entry_slabs=entry_slabs,
                     per_link_stats=shards.per_link_stats,
                 )
             pool = AerSessionPool(cc, engine, cfg, models=self.models)

@@ -3,11 +3,12 @@
 Covers the acceptance criteria of the batched-dispatch refactor and the
 event-sparse delivery layer:
   * batched step/run == independent single runs (B=3 vs 3x B=1)
-  * every registered backend (reference / pallas / sharded / fused) matches
-    the dense oracle for B in {1, 4} at activity levels {1%, 10%, 100%},
+  * every built-in backend (reference / pallas / sharded / fused / fabric,
+    the last in its collapsed single-step mode on a 2x2 mesh) matches the
+    dense oracle for B in {1, 4} at activity levels {1%, 10%, 100%},
     dense and event-queued (queue below capacity)
   * the batched Pallas kernels match the batched jnp reference
-  * registry ergonomics (unknown names, instance pass-through)
+  * lookup ergonomics (unknown names, instance pass-through)
 """
 
 import numpy as np
@@ -17,28 +18,35 @@ import pytest
 
 from repro.core.dispatch import (
     DispatchBackend,
+    FabricBackend,
     FusedBackend,
     PallasBackend,
     available_backends,
     get_backend,
+    two_stage_deliver,
 )
 from repro.core.event_engine import EventEngine, dense_weights_from_tables
+from repro.core.routing import Fabric
 from repro.core.tags import NetworkSpec, compile_network
-from repro.core.two_stage import stage1_route, stage2_cam_match, two_stage_deliver
+from repro.core.two_stage import stage1_route, stage2_cam_match
 from repro.kernels.cam_match.cam_match import cam_match_pallas
 from repro.kernels.cam_match.ref import cam_match_ref
 
 
-ALL_BACKENDS = ["reference", "pallas", "sharded", "fused"]
+ALL_BACKENDS = ["reference", "pallas", "sharded", "fused", "fabric"]
 
 
 def _bk(name):
     """'pallas'/'fused' with the platform default would fall back to the jnp
-    reference on CPU; force interpret mode so CI exercises the real kernels."""
+    reference on CPU; force interpret mode so CI exercises the real kernels.
+    'fabric' puts the test networks' 3 clusters on 3 tiles of a 2x2 mesh, so
+    events cross links."""
     if name == "pallas":
         return PallasBackend(interpret=True)
     if name == "fused":
         return FusedBackend(interpret=True)
+    if name == "fabric":
+        return FabricBackend(fabric=Fabric(grid_x=2, grid_y=2, cores_per_tile=1))
     return name
 
 
@@ -57,15 +65,16 @@ def _tables(seed, n=48, cluster=16, k=48, edges=60):
 
 
 # ---------------------------------------------------------------------------
-# registry
+# backend lookup
 # ---------------------------------------------------------------------------
 def test_registry_lists_all_builtin_backends():
-    assert {"reference", "pallas", "sharded", "fused"} <= set(available_backends())
+    assert set(available_backends()) == {"reference", "pallas", "sharded", "fused", "fabric"}
 
 
-def test_unknown_backend_raises_with_choices():
+@pytest.mark.parametrize("name", ["no-such-backend", "auto"])
+def test_unknown_backend_raises_with_choices(name):
     with pytest.raises(ValueError, match="unknown dispatch backend"):
-        get_backend("no-such-backend")
+        EventEngine(_tables(0), backend=name)
 
 
 def test_instance_passes_through_and_options_construct():

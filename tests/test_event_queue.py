@@ -13,6 +13,8 @@ The contract under test:
     stats stack over the scan's time axis.
 """
 
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -20,7 +22,7 @@ import pytest
 
 from tests._hypothesis_compat import HAS_HYPOTHESIS, given, settings, st
 
-from repro.core.dispatch import available_backends, get_backend
+from repro.core.dispatch import available_backends, get_backend, two_stage_deliver
 from repro.core.event_engine import EventEngine, dense_weights_from_tables
 from repro.core.tags import NetworkSpec, compile_network
 from repro.core.two_stage import (
@@ -28,7 +30,6 @@ from repro.core.two_stage import (
     compact_events,
     stage1_route,
     stage1_route_events,
-    two_stage_deliver,
 )
 
 
@@ -339,53 +340,20 @@ def test_engine_donate_carry_threads_correctly():
         np.testing.assert_allclose(np.asarray(s_d), np.asarray(s_r), atol=1e-6)
 
 
-def test_legacy_backend_signature_still_works():
-    """Backends registered before event-sparse delivery (no queue_capacity /
-    syn_onehot / with_stats keywords) must keep working through both
-    two_stage_deliver and EventEngine; asking them for a queue raises."""
-    from repro.core.dispatch import DispatchBackend, register_backend
-    from repro.core.two_stage import stage1_route, stage2_cam_match
-
-    @register_backend("_test_legacy")
-    class LegacyBackend(DispatchBackend):
-        # the pre-§10 deliver signature, verbatim
-        def deliver(self, spikes, src_tag, src_dest, cam_tag, cam_syn,
-                    cluster_size, k_tags, external_activity=None):
-            a = stage1_route(spikes, src_tag, src_dest,
-                             spikes.shape[-1] // cluster_size, k_tags)
-            if external_activity is not None:
-                a = a + external_activity
-            return stage2_cam_match(a, cam_tag, cam_syn, cluster_size)
-
-    try:
-        tables = _tables(17)
-        rng = np.random.default_rng(18)
-        spikes = jnp.asarray(rng.random((2, tables.n_neurons)) < 0.3, jnp.float32)
-        args = _deliver_args(tables)
-        ref = two_stage_deliver(spikes, *args)
-        # plain delivery passes no new kwargs through
-        out = two_stage_deliver(spikes, *args, backend="_test_legacy")
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
-        # with_stats is synthesized (zero drops), syn_onehot dropped silently
-        out, stats = two_stage_deliver(
-            spikes, *args, backend="_test_legacy", with_stats=True,
-            syn_onehot=jnp.zeros((tables.n_neurons, tables.cam_tag.shape[1], 4)),
-        )
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
-        np.testing.assert_array_equal(np.asarray(stats.dropped), 0)
-        # the engine always requests stats internally — still fine
-        eng = EventEngine(tables, backend="_test_legacy")
-        carry, spikes_out = eng.step(eng.init_state(batch=2),
-                                     jnp.zeros((2, tables.n_clusters, tables.k_tags)))
-        assert spikes_out.shape == (2, tables.n_neurons)
-        # a queue is a semantic request a legacy backend cannot honor
-        with pytest.raises(ValueError, match="does not support queue_capacity"):
-            two_stage_deliver(spikes, *args, backend="_test_legacy",
-                              queue_capacity=8)
-    finally:
-        from repro.core import dispatch as _dispatch
-
-        _dispatch._REGISTRY.pop("_test_legacy", None)
+@pytest.mark.parametrize("lossless", [True, False])
+def test_queue_compacts_only_below_capacity_n(lossless):
+    """A queue of capacity >= N is lossless, so the step skips compaction and
+    routes dense: no op of the compiled step lies under scope ``compact``. A
+    smaller queue can drop, so its step compacts."""
+    tables = _tables(19)
+    n = tables.n_neurons
+    eng = EventEngine(tables, queue_capacity=n if lossless else n // 4)
+    b = 2
+    inp = jax.ShapeDtypeStruct((b, tables.n_clusters, tables.k_tags), jnp.float32)
+    text = eng.compiled_step_text(eng.init_state(batch=b), inp)
+    scoped = [path for path in re.findall(r'op_name="([^"]*)"', text)
+              if "compact" in re.split(r"[/;]", path)]
+    assert not scoped if lossless else scoped
 
 
 def test_engine_sharded_backend_queue_single_device():

@@ -623,6 +623,29 @@ def test_degraded_repair_restores_full_accuracy(tuned_cc):
     assert ld_repaired < ld_faulted
 
 
+def test_one_dead_link_drops_events_and_repair_reduces_the_drops():
+    """One dead mesh link on a pool of 2: the served fabric measures link
+    drops, and serving the same sessions on repair_placement's placement
+    measures fewer."""
+    cc = compile_poker_cnn()
+    fs = FaultSpec(dead_links=((0, 1),))
+    placement, report = repair_placement(cc.tables, Fabric(), fs, seed=0)
+    assert report["feasible"]
+    cc_r = dataclasses.replace(
+        cc, tables=dataclasses.replace(cc.tables, tile_of_cluster=placement)
+    )
+
+    def drops(c):
+        eng = build_poker_engine(c.tables, backend="fabric", donate_carry=False,
+                                 faults=fs)
+        pool = AerSessionPool(c, eng, AerServeConfig(pool_size=2, max_steps=12))
+        return sum(r.link_dropped for r in pool.serve(_poker_sessions(4)))
+
+    d_fault = drops(cc)
+    assert d_fault > 0
+    assert drops(cc_r) < d_fault
+
+
 def test_degraded_pool_migrates_mid_flight_to_repaired_engine(tuned_cc):
     """Full escalation: watchdog detects the sustained link-drop rate,
     serve_resilient hands the pool to on_degraded, the sessions migrate via

@@ -1,11 +1,9 @@
 """Multi-model fabric serving (DESIGN.md §16).
 
-Locks the three load-bearing claims of multi-tenant multi-model residency:
+Locks the load-bearing claims of multi-tenant multi-model residency:
 
-  * **Slab conformance** — the ring fast path's entry table built
-    slab-by-slab (``build_fabric_entries_slabs``) is bit-identical to the
-    one built from the concatenated tables, so per-model compilation and
-    combined execution describe the same machine.
+  * **Slab layout** — resident models' tables concatenate back to back,
+    each slab keeping its solo connectivity at its neuron offset.
   * **Serving isolation** — a session served from an N-model pool is
     bit-identical (queued mode) to the same session served solo, through
     admits, hot model loads under live sessions, and checkpoint restore;
@@ -25,14 +23,9 @@ from repro.core.cnn import compile_poker_cnn
 from repro.core.compiler import Geometry, artifact_from_tables
 from repro.core.event_engine import EventEngine, ModelRegistry, reset_slots
 from repro.core.neuron import NeuronParams
-from repro.core.routing import build_delivery_model, default_tile_of_cluster
 from repro.core.tags import NetworkSpec, compile_network, concat_tables
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.data.pipeline import DvsStreamConfig, DvsStreamSource
-from repro.kernels.fabric_deliver.ops import (
-    build_fabric_entries,
-    build_fabric_entries_slabs,
-)
 from repro.serve.aer import (
     AerServeConfig,
     AerSessionPool,
@@ -72,39 +65,12 @@ def _random_tables(seed, n=32, cluster=8, k=24, edges=48):
     return compile_network(spec)
 
 
-# ---------------------------------------------------------------------------
-# Slab conformance: per-model entry construction == concatenated construction
-# ---------------------------------------------------------------------------
-def test_entry_table_slabs_bit_identical_to_concat():
-    parts = [_random_tables(0), _random_tables(1, n=48, k=40), _random_tables(2)]
+def test_concat_tables_dense_equivalents_stack():
+    """Each slab's dense connectivity is the solo table's, offset intact."""
+    parts = [_random_tables(3), _random_tables(4, n=48, k=40), _random_tables(5)]
     combined, slabs = concat_tables(parts)
     assert [s.neuron_lo for s in slabs] == [0, 32, 80]
     assert combined.k_tags == 40  # padded to the widest resident model
-
-    fab = Geometry(grid_x=2, grid_y=2, cores_per_tile=4, neurons_per_core=8).fabric()
-    placement = default_tile_of_cluster(combined.n_clusters, fab)
-    model = build_delivery_model(fab, combined.n_clusters, 1e-3,
-                                 tile_of_cluster=placement)
-    direct = build_fabric_entries(
-        combined.src_tag, combined.src_dest, combined.cluster_size,
-        combined.k_tags, model,
-    )
-    slabbed = build_fabric_entries_slabs(
-        [(t.src_tag, t.src_dest) for t in parts],
-        combined.cluster_size, combined.k_tags, model,
-    )
-    for f in ("src", "dstk", "delay", "cross", "link_start", "hops",
-              "latency_s", "energy_j", "valid", "alive"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(direct, f)), np.asarray(getattr(slabbed, f)),
-            err_msg=f,
-        )
-
-
-def test_concat_tables_dense_equivalents_stack():
-    """Each slab's dense connectivity is the solo table's, offset intact."""
-    parts = [_random_tables(3), _random_tables(4)]
-    combined, slabs = concat_tables(parts)
     got = np.asarray(combined.dense_equivalent())
     rows = []
     for t, s in zip(parts, slabs):

@@ -146,6 +146,37 @@ def test_forced_swap_is_byte_equal_for_mid_flight_sessions():
         assert sa.dropped == sb.dropped and sa.link_dropped == sb.link_dropped
 
 
+def test_replacement_never_increases_measured_link_drops():
+    """A scattered ("stale") placement under 2-event link FIFOs drops
+    events. Re-placing from the observed traffic and serving the same cohort
+    for the same window on the new version measures no more link drops."""
+    cc = _poker_cc()
+    stale = np.array([0, 8, 2, 6, 4, 5], np.int32)  # corners first
+    cc_stale = dataclasses.replace(
+        cc, tables=dataclasses.replace(cc.tables, tile_of_cluster=stale))
+    pool = AerSessionPool.from_models(
+        {"m": cc_stale}, AerServeConfig(pool_size=2, max_steps=10**6),
+        backend="fabric",
+        fabric_options={"link_capacity": 2, "per_link_stats": True})
+    window = 8
+    _fill(pool, model="m", seed=23)
+    for _ in range(window):
+        pool.step()
+    drops_pre = pool.profile.total_link_dropped
+    ctl = ReplacementController(pool, cfg=ReplacementConfig(
+        min_steps=window // 2, cooldown_steps=0))
+    assert ctl.maybe_replace(force=True) is not None
+    for i in range(pool.cfg.pool_size):
+        pool.evict(i)
+    ctl.drain_retired()  # the rebind restarts the observation window
+    for i in range(pool.cfg.pool_size):
+        pool.admit(ctl.retarget(_session(i, model="m", seed=23)))
+    for _ in range(window):
+        pool.step()
+    assert drops_pre > 0
+    assert pool.profile.total_link_dropped <= drops_pre
+
+
 def test_versioned_swap_byte_equal_in_queued_mode():
     """The controller itself needs fabric per-link stats, but the swap
     primitive it rides — a versioned ``load_model`` rebind — is

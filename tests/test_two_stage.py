@@ -13,7 +13,8 @@ from repro.core.event_engine import (
 )
 from repro.core.neuron import NeuronParams, init_state
 from repro.core.tags import NetworkSpec, compile_network
-from repro.core.two_stage import stage1_route, stage2_cam_match, two_stage_deliver
+from repro.core.dispatch import two_stage_deliver
+from repro.core.two_stage import stage1_route, stage2_cam_match
 
 
 def _tables(seed, n=48, cluster=16, k=48, edges=60):
