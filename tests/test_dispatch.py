@@ -299,6 +299,58 @@ def test_fused_deliver_scans_only_occupied_entries(monkeypatch, active, capacity
         assert not np.array_equal(np.asarray(full), np.asarray(bounded))
 
 
+@pytest.mark.parametrize("case", ["one-slot", "several-blocks", "empty-rows", "two-resident"])
+def test_fused_deliver_slot_blocks_bit_identical(case):
+    """Integer spikes and external input, as served: the slot-blocked kernel
+    equals the jnp reference exactly with one slot, with more slots than one
+    block holds (several blocks and padded slots, each slot's stage 1
+    bounded by its own ``n_entries``), with slots whose queue is empty
+    (``n_entries = 0``: the external row alone, or nothing), and on a
+    two-resident table where half of every slot's clusters get nothing."""
+    from repro.core.two_stage import compact_events
+    from repro.kernels.fused_deliver import fused_deliver, fused_deliver_ref
+    from repro.kernels.fused_deliver.fused_deliver import fused_plan
+    from repro.kernels.fused_deliver.ops import _event_entries_flat
+
+    b = {"one-slot": 1, "several-blocks": 130}.get(case, 6)
+    rng = np.random.default_rng(b + len(case))
+    ncl, c, s, k, e = 4, 16, 8, 32, 4
+    n, half = ncl * c, ncl * c // 2
+    src_tag = rng.integers(-1, k, (n, e))
+    src_dest = rng.integers(0, ncl, (n, e))
+    spikes = (rng.random((b, n)) < 0.3) * rng.integers(1, 4, (b, n))
+    ext = (rng.random((b, ncl, k)) < 0.1) * 8.0
+    if case == "two-resident":  # each half routes within itself; slot i runs half i % 2
+        src_dest = src_dest % (ncl // 2) + np.where(np.arange(n) < half, 0, ncl // 2)[:, None]
+        for i in range(b):
+            spikes[i, (1 - i % 2) * half:(2 - i % 2) * half] = 0
+            ext[i, (1 - i % 2) * ncl // 2:(2 - i % 2) * ncl // 2] = 0.0
+    if case == "empty-rows":
+        spikes[::2] = 0
+        ext[::4] = 0.0
+    if case == "several-blocks":  # a slot's block-mate one block on differs
+        spikes[::5] = 0
+    queue = compact_events(jnp.asarray(spikes, jnp.float32), n)
+    args = (
+        queue, jnp.asarray(src_tag, jnp.int32), jnp.asarray(src_dest, jnp.int32),
+        jnp.asarray(rng.integers(-1, k, (n, s)), jnp.int32),
+        jnp.asarray(rng.integers(0, 4, (n, s)), jnp.int32), c, k,
+    )
+    ext = jnp.asarray(ext, jnp.float32)
+    n_entries = np.asarray(_event_entries_flat(queue, args[1], args[2], k)[2])
+    if case == "empty-rows":
+        assert (n_entries[::2] == 0).all() and (n_entries[1::2] > 0).all()
+    bb, b_pad = fused_plan(b, k, s, n * e, block_c=8)[:2]
+    assert (bb, b_pad) == ((72, 144) if b == 130 else (b, b))
+    out_k = fused_deliver(*args, external_activity=ext, block_c=8, interpret=True)
+    out_r = fused_deliver_ref(*args, external_activity=ext)
+    assert out_k.shape == (b, n, 4)
+    np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
+    if case == "two-resident":  # the silent half really is silent
+        silent = np.asarray(out_r).reshape(b, 2, half, 4)[np.arange(b), 1 - np.arange(b) % 2]
+        assert not silent.any() and np.asarray(out_r).any()
+
+
 # ---------------------------------------------------------------------------
 # engine: batched carry == independent single runs
 # ---------------------------------------------------------------------------

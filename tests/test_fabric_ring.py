@@ -281,6 +281,55 @@ def test_ring_kernel_interpret_matches_jnp():
         _assert_stats_equal(s_j, s_k, f"step {t}")
 
 
+@pytest.mark.parametrize("b", [1, 130], ids=["one-slot", "several-blocks"])
+def test_ring_kernel_slot_blocks_bit_identical(b):
+    """Integer spikes and external input, as served: the slot-blocked
+    fabric_deliver kernel (interpret mode) equals the jnp fast path exactly,
+    drives and rings, with one slot and with more slots than one block holds
+    (several blocks and padded slots). A link capacity of 1 drops entries to
+    weight 0, and the steps outnumber the ring slots, so the cursor wraps."""
+    from repro.kernels.fabric_deliver.fabric_deliver import fabric_plan
+
+    rng = np.random.default_rng(b)
+    fab = Fabric(grid_x=2, grid_y=1, cores_per_tile=2,
+                 constants=ChipConstants(latency_across_chip_s=DT))
+    nc, cs, k = fab.n_cores, 4, 8
+    n = nc * cs
+    src_tag, src_dest, cam_tag, cam_syn = _random_tables(rng, n, nc, k)
+    be_j = FabricBackend(fabric=fab, dt=DT, link_capacity=1)
+    be_k = FabricBackend(fabric=fab, dt=DT, link_capacity=1, interpret=True)
+    entries = be_j.build_entries(src_tag, src_dest, cs, k)
+    model, _ = be_j.model_for(nc)
+    d1 = model.max_delay + 1
+    assert d1 >= 2
+    m = entries.src.shape[0]
+    bb, b_pad = fabric_plan(b, k, cam_tag.shape[1], m, d1, block_c=cs)[:2]
+    assert (bb, b_pad) == ((72, 144) if b == 130 else (b, b))
+    ring_j, cur_j = be_j.init_ring(nc, k, batch=b)
+    ring_k, cur_k = be_k.init_ring(nc, k, batch=b)
+    link_dropped = 0
+    for t in range(d1 + 1):
+        spikes = jnp.asarray((rng.random((b, n)) < 0.5) * rng.integers(1, 4, (b, n)),
+                             jnp.float32)
+        ext = jnp.asarray((rng.random((b, nc, k)) < 0.1) * 8.0, jnp.float32)
+        d_j, ring_j, cur_j, s_j = be_j.deliver_fabric_ring(
+            spikes, entries, cam_tag, cam_syn, cs, k, ring_j, cur_j,
+            external_activity=ext,
+        )
+        d_k, ring_k, cur_k, s_k = be_k.deliver_fabric_ring(
+            spikes, entries, cam_tag, cam_syn, cs, k, ring_k, cur_k,
+            external_activity=ext,
+        )
+        np.testing.assert_array_equal(np.asarray(d_j), np.asarray(d_k), err_msg=f"step {t}")
+        np.testing.assert_array_equal(np.asarray(ring_j), np.asarray(ring_k),
+                                      err_msg=f"step {t}")
+        _assert_stats_equal(s_j, s_k, f"step {t}")
+        link_dropped += int(np.asarray(s_k.link_dropped).sum())
+    assert int(cur_k) == (d1 + 1) % d1 == int(cur_j)
+    assert link_dropped > 0  # arbitration zeroed some entries' weights
+    assert np.asarray(ring_k).any()  # events in transit across the wrap
+
+
 # ---------------------------------------------------------------------------
 # building blocks: scatter helper + dense stage-1 shortcut
 # ---------------------------------------------------------------------------

@@ -4,7 +4,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.cam_match.cam_match import cam_match_pallas
+from repro.kernels.cam_match.cam_match import cam_match_pallas, cam_match_plan
 from repro.kernels.cam_match.ref import cam_match_ref
 from repro.kernels.rwkv6.ref import rwkv6_chunk_ref
 from repro.kernels.rwkv6.rwkv6 import rwkv6_chunk_pallas
@@ -51,6 +51,32 @@ def test_cam_match_empty_cam_rows():
     syn = jnp.zeros((8, 4), jnp.int32)
     out = cam_match_pallas(act, tag, syn, 4, block_c=4)
     assert float(jnp.abs(out).max()) == 0.0
+
+
+@pytest.mark.parametrize(
+    "b,resident_halves", [(1, False), (130, False), (6, True)],
+    ids=["one-slot", "several-blocks", "two-resident"],
+)
+def test_cam_match_slot_blocks_bit_identical(b, resident_halves):
+    """Integer activities, as served (event counts x drive): the slot-blocked
+    kernel equals the jnp reference exactly with one slot, with more slots
+    than one block holds (several blocks and padded slots), and with half of
+    every slot's clusters silent, as in a pool of two residents."""
+    rng = np.random.default_rng(b)
+    ncl, c, s, k = 4, 16, 8, 32
+    n = ncl * c
+    act = (rng.random((b, ncl, k)) < 0.3) * 8.0 * rng.integers(1, 5, (b, ncl, k))
+    if resident_halves:  # slot i holds resident i % 2: its other half is silent
+        for i in range(b):
+            act[i, (1 - i % 2) * ncl // 2:(2 - i % 2) * ncl // 2] = 0.0
+    act = jnp.asarray(act, jnp.float32)
+    tag = jnp.asarray(rng.integers(-1, k, (n, s)), jnp.int32)
+    syn = jnp.asarray(rng.integers(0, 4, (n, s)), jnp.int32)
+    bb, b_pad, _ = cam_match_plan(b, k, s, 8)
+    assert (bb, b_pad) == ((72, 144) if b == 130 else (b, b))
+    out_k = cam_match_pallas(act, tag, syn, c, block_c=8)
+    assert out_k.shape == (b, n, 4)
+    np.testing.assert_array_equal(np.asarray(out_k), np.asarray(cam_match_ref(act, tag, syn, c)))
 
 
 @pytest.mark.parametrize(

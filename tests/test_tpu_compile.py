@@ -22,14 +22,19 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.cam_match import cam_match as cm
 from repro.kernels.cam_match.cam_match import cam_match_pallas
-from repro.kernels.fabric_deliver.fabric_deliver import fabric_deliver_ring_pallas
-from repro.kernels.fused_deliver.fused_deliver import fused_deliver_pallas
+from repro.kernels.fabric_deliver.fabric_deliver import (
+    fabric_deliver_ring_pallas,
+    fabric_plan,
+)
+from repro.kernels.fused_deliver.fused_deliver import fused_deliver_pallas, fused_plan
 
 POOL, C, K, S, E = 32, 256, 1024, 64, 16
 MAX_DELAY = 1  # the served fabric's delay horizon
 CELL_POOL = 128  # slots of both benchmark cells
 CELL_ENTRIES = 1280  # occupied SRAM entries of one Table-V CNN
+CELL_BLOCKS = {"fused_deliver": 128, "fabric_deliver": 128}  # slots a grid step
 STEP_SCOPES = {"compact", "link_arbitration", "deliver", "neuron_update", "reset_slots"}
 
 
@@ -70,6 +75,17 @@ def _compile(fn, *shapes):
     return text, compiled
 
 
+def _kernel_calls(hlo: str, kernel: str) -> int:
+    """Custom calls of the compiled program named for ``kernel``."""
+    return len(re.findall(rf"%{kernel}(\.\d+)? = [^\n]*custom-call\(", hlo))
+
+
+def _vmem_limit(text: str) -> int:
+    """The VMEM limit the lowered kernel call carries."""
+    (size,) = re.findall(r"scoped_memory_configs\\22: \[\{[^}]*size\\22: (\d+)", text)
+    return int(size)
+
+
 def _kernel_call(kernel: str, nc: int, sds, pool: int = POOL, m: int | None = None):
     n = nc * C
     tags = sds((n, S), jnp.int32)
@@ -85,8 +101,8 @@ def _kernel_call(kernel: str, nc: int, sds, pool: int = POOL, m: int | None = No
             lambda f, w, t, s, x, ne: fused_deliver_pallas(
                 f, w, t, s, x, C, K, n_entries=ne, interpret=False
             ),
-            sds((POOL, qe), jnp.int32), sds((POOL, qe)), tags, tags,
-            sds((POOL, nc, K)), sds((POOL,), jnp.int32),
+            sds((pool, qe), jnp.int32), sds((pool, qe)), tags, tags,
+            sds((pool, nc, K)), sds((pool,), jnp.int32),
         )
     m = n if m is None else m  # occupied SRAM entries: about one per neuron
     return (
@@ -122,6 +138,51 @@ def test_fabric_kernel_compiles_for_v5e_at_the_cell_shapes(one_chip):
     text, compiled = _compile(fn, *shapes)
     assert "tpu_custom_call" in text
     assert compiled.memory_analysis() is not None
+    # compiled under the limit the wrapper set from its own estimate, as one call
+    bb, _, _, _, vmem = fabric_plan(CELL_POOL, K, S, CELL_ENTRIES, MAX_DELAY + 1)
+    assert bb == CELL_BLOCKS["fabric_deliver"]
+    assert _vmem_limit(text) == vmem + cm._VMEM_HEADROOM
+    assert _kernel_calls(compiled.as_text(), "fabric_deliver") == 1
+
+
+def test_fused_kernel_compiles_for_v5e_at_the_cell_shapes(one_chip):
+    """``fused_deliver`` as the fused cell runs it: 128 slots, two residents
+    (nc 12) and the lossless queue's 49,152 entries a slot, compiled under
+    the VMEM limit the wrapper sets, as one call."""
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, *shapes = _kernel_call("fused_deliver", 12, sds, pool=CELL_POOL)
+    text, compiled = _compile(fn, *shapes)
+    assert "tpu_custom_call" in text
+    assert compiled.memory_analysis() is not None
+    bb, _, _, _, vmem = fused_plan(CELL_POOL, K, S, 12 * C * E)
+    assert bb == CELL_BLOCKS["fused_deliver"]
+    assert _vmem_limit(text) == vmem + cm._VMEM_HEADROOM
+    assert _kernel_calls(compiled.as_text(), "fused_deliver") == 1
+
+
+def test_slot_blocks_and_planes_a_step_at_the_cell_shapes():
+    """The slot block each cell's kernel derives from its shapes, and the
+    compare planes it builds a step: once per block of slots, where the
+    kernels built them once per slot (a block of 1)."""
+    def fused_stage2(bb, b_pad):
+        return b_pad // bb * 12 * (C // 16)
+
+    bb, b_pad, *_ = fused_plan(CELL_POOL, K, S, 12 * C * E)
+    assert (bb, b_pad) == (CELL_BLOCKS["fused_deliver"], CELL_POOL)
+    assert fused_stage2(1, CELL_POOL) == 24_576
+    assert fused_stage2(bb, b_pad) == 192 * -(-CELL_POOL // bb) == 192
+
+    bb, b_pad, chunk, m_pad, _ = fabric_plan(CELL_POOL, K, S, CELL_ENTRIES, MAX_DELAY + 1)
+    assert (bb, b_pad, chunk, m_pad) == (CELL_BLOCKS["fabric_deliver"], CELL_POOL, 512, 1536)
+
+    def fabric_stages(bb):
+        blocks = -(-CELL_POOL // bb)
+        return blocks * 6 * (C // 16), blocks * 6 * (m_pad // chunk) * (MAX_DELAY + 1)
+
+    assert fabric_stages(1) == (12_288, 4_608)
+    assert fabric_stages(bb) == (96, 36)
 
 
 @pytest.mark.parametrize("kernel", ["cam_match", "fused_deliver", "fabric_deliver"])
@@ -219,7 +280,7 @@ def test_one_model_fabric_pool_step_compiles_for_v5e_at_128_slots(one_chip):
     text, compiled = _compile(eng.step, carry, inp)
     assert "tpu_custom_call" in text
     hlo = compiled.as_text()
-    assert re.search(r"%fabric_deliver(\.\d+)? = [^\n]*custom-call\(", hlo)
+    assert _kernel_calls(hlo, "fabric_deliver") == 1
     entry = re.search(r"\nENTRY [^\n]*\{\n(.*?)\n\}", hlo, re.S).group(1)
     named = re.findall(r"%([\w.\-]+) = [^\n]*? (\w[\w\-]*)\([^\n]*op_name=\"([^\"]*)\"", entry)
     params = {path for _, op, path in named if op == "parameter"}
@@ -257,7 +318,7 @@ def test_two_model_fused_pool_step_at_128_slots_has_no_compaction_loop(one_chip)
     text, compiled = _compile(eng.step, carry, inp)
     assert "tpu_custom_call" in text
     hlo = compiled.as_text()
-    assert re.search(r"%fused_deliver(\.\d+)? = [^\n]*custom-call\(", hlo)
+    assert _kernel_calls(hlo, "fused_deliver") == 1
     scoped = re.findall(r"%[\w.\-]+ = [^\n]*? (\w[\w\-]*)\([^\n]*op_name=\"([^\"]*)\"", hlo)
     # the scatter keeps its scope, so a trace reduction counts it as compaction
     assert any(op == "fusion" and path.endswith("/compact/scatter") for op, path in scoped)
